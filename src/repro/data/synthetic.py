@@ -11,6 +11,7 @@ tolerance) and identically shardable across hosts.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 
 import jax
 import jax.numpy as jnp
@@ -34,8 +35,9 @@ DATASETS = {"cifar10": CIFAR10_LIKE, "gsc": GSC_LIKE,
 
 
 def _templates(spec: ClassificationSpec) -> jax.Array:
-    """Smooth per-class templates, fixed by the dataset name."""
-    key = jax.random.key(abs(hash(spec.name)) % (2 ** 31))
+    """Smooth per-class templates, fixed by the dataset name (through a
+    stable checksum: ``hash()`` of a string changes with every process)."""
+    key = jax.random.key(zlib.crc32(spec.name.encode()) % (2 ** 31))
     h, w, c = spec.shape
     # low-frequency template: upsampled coarse noise
     coarse = jax.random.normal(key, (spec.num_classes, max(h // 4, 1),

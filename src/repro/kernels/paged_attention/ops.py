@@ -1,18 +1,19 @@
 """Dispatch wrapper for paged attention (decode AND prefill): kernel on
-TPU, gathered view off-TPU, exact-mirror reference for tests.
+TPU, gathered view off-TPU, tolerance-oracle reference for tests.
 
 ``impl`` resolution (also overridable process-wide via :func:`force_impl`
 for tests; the override pins BOTH entry points):
 
-* ``"kernel"`` -- the Pallas kernels (compiled on TPU, interpret mode
-  elsewhere).  The production TPU path.
+* ``"kernel"`` -- the Pallas kernels.  The default on TPU, where they
+  are always compiled; forced elsewhere (tests), they run in interpret
+  mode.
 * ``"view"``   -- the gathered dense view + the dense attention op
   sequence (``decode_attention`` for decode, ``flash_attention`` for
   prefill); bitwise identical to the dense cache backend, and the fast
   formulation for CPU/GPU where the pool gather compiles to one fused
   XLA op.
-* ``"ref"``    -- the bitwise mirrors of the kernels (python-looped;
-  oracles only).
+* ``"ref"``    -- the kernels' math in plain jnp, python-looped; equal
+  to the kernels within a few f32 ULP (oracles only).
 """
 from __future__ import annotations
 

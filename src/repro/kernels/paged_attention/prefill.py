@@ -28,11 +28,12 @@ place**:
     shared K page -- no head-repeated materialization.
 
 Numerics contract: identical to decode -- masked positions score
-``-1e30``, the two optimization barriers pin the rescale-then-add pair,
-and :func:`paged_prefill_ref` mirrors the kernel operation-for-operation
-(the tests assert bitwise equality in interpret mode).  Because every
-output row is an independent online softmax over its own key range, the
-result is bitwise independent of the q-chunk width.  Padded query rows
+``-1e30`` and :func:`paged_prefill_ref` mirrors the kernel
+operation-for-operation; the tests hold the two to a few f32 ULP, since
+whether each page's rescale-then-add contracts into an FMA depends on
+how each graph is compiled.  Because every output row is an independent
+online softmax over its own key range, the q-chunk width changes nothing
+beyond that rounding.  Padded query rows
 (positions at or beyond the slot's ``lens``) produce finite garbage that
 the caller discards; they never influence real rows (causality).
 
@@ -92,8 +93,8 @@ def prefill_page_update(q, k, v, m, l, acc, page_start, qc_start, *,
                         scale: float, window: int, chunked: bool,
                         cap: float):
     """One page's online-softmax contribution for one q chunk.  Shared by
-    the kernel body and :func:`paged_prefill_ref` so the two are bitwise
-    identical.
+    the kernel body and :func:`paged_prefill_ref` so the two compute the
+    same math.
 
     q: (Q, H, D) f32; k/v: (T, Hkv, D) f32; m/l: (Q, H, 1) f32 running
     max/denominator; acc: (Q, H, D) f32.  Returns updated (m, l, acc).
@@ -117,12 +118,7 @@ def prefill_page_update(q, k, v, m, l, acc, page_start, qc_start, *,
     m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
     corr = jnp.exp(m - m_new)
-    # same contract as decode's page_update: the barriers pin the
-    # rescale-then-add to two instructions in BOTH consumers, so the
-    # kernel (VMEM scratch round-trips) and the python-looped reference
-    # stay bitwise identical on multi-page prompts
-    l_new = jax.lax.optimization_barrier(l * corr) \
-        + jnp.sum(p, axis=-1, keepdims=True)
+    l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
     outs = []
     for i in range(hkv):
         pg = p[:, i * g:(i + 1) * g, :].reshape(qc * g, t)
@@ -130,8 +126,7 @@ def prefill_page_update(q, k, v, m, l, acc, page_start, qc_start, *,
             pg, v[:, i, :], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32
         ).reshape(qc, g, d))                               # (Q, G, D)
-    acc_new = jax.lax.optimization_barrier(acc * corr) \
-        + jnp.concatenate(outs, axis=1)
+    acc_new = acc * corr + jnp.concatenate(outs, axis=1)
     return m_new, l_new, acc_new
 
 
@@ -229,15 +224,13 @@ def paged_prefill_ref(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                       tables: jax.Array, lens: jax.Array, *,
                       window: int = 0, chunked: bool = False,
                       cap: float = 0.0, q_chunk: int = 16) -> jax.Array:
-    """Bitwise mirror of the Pallas prefill kernel: the same python loop
-    over KV head groups, the same per-(q-chunk, page) 2-D dots, the same
-    online-softmax update order (it calls the kernel's own
+    """Tolerance oracle of the Pallas prefill kernel: the same python
+    loop over KV head groups, the same per-(q-chunk, page) 2-D dots, the
+    same online-softmax update order (it calls the kernel's own
     :func:`prefill_page_update`).  Slots and q chunks unroll in python;
     the page axis is a ``lax.fori_loop`` whose carried state mirrors the
     kernel's VMEM scratch and whose ``lax.cond`` mirrors the ``pl.when``
-    dead-page skip -- XLA compiles a python-unrolled page chain with
-    different elementwise fusion than the kernel's sequential grid, so
-    the loop structure itself is part of the bitwise contract.  An
+    dead-page skip.  It agrees with the kernel to a few f32 ULP.  An
     oracle, not a fast path."""
     b, s, h, d = q.shape
     page_size = k_pool.shape[1]

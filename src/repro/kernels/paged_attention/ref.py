@@ -2,11 +2,12 @@
 
 Two oracles with different jobs:
 
-* :func:`paged_attention_ref` -- the EXACT mirror of ``kernel.py``: the
-  same python loop over KV head groups, the same per-page 2-D dots, the
-  same online-softmax update order (it calls the kernel's own
-  :func:`~repro.kernels.paged_attention.kernel.page_update`).  Kernel
-  tests assert bitwise equality against it in interpret mode.  It loops
+* :func:`paged_attention_ref` -- the tolerance oracle of ``kernel.py``:
+  the same python loop over KV head groups, the same per-page 2-D dots,
+  the same online-softmax update order (it calls the kernel's own
+  :func:`~repro.kernels.paged_attention.kernel.page_update`).  The two
+  agree to a few f32 ULP: whether each page's rescale-then-add is
+  contracted into an FMA depends on how each graph is compiled.  It loops
   over slots and pages in python, so it is an oracle, not a fast path.
 
 * :func:`paged_attention_view` -- the production off-TPU fallback: one
@@ -31,7 +32,7 @@ def paged_attention_ref(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                         tables: jax.Array, pos: jax.Array, *,
                         window: int = 0, chunked: bool = False,
                         cap: float = 0.0) -> jax.Array:
-    """Bitwise mirror of the Pallas kernel (see module docstring).
+    """Tolerance oracle of the Pallas kernel (see module docstring).
 
     q: (B, H, D); k_pool/v_pool: (n_pages + 1, page_size, Hkv, D);
     tables: (B, P); pos: (B,).  Returns (B, H, D) in q's dtype.
@@ -65,10 +66,6 @@ def paged_attention_ref(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
             m = jnp.where(live, m2, m)
             l = jnp.where(live, l2, l)
             acc = jnp.where(live, a2, acc)
-            # the kernel round-trips its state through VMEM scratch each
-            # page; the barrier stops XLA from FMA-fusing across pages
-            # here, keeping the two float pipelines bitwise identical
-            m, l, acc = jax.lax.optimization_barrier((m, l, acc))
         outs.append((acc / jnp.maximum(l, 1e-30)).astype(q.dtype))
     return jnp.stack(outs)
 

@@ -31,15 +31,19 @@ def _pad_to(x, m, axis):
 def quant_matmul(xq: jax.Array, wq_packed: jax.Array, sw: jax.Array,
                  sx: jax.Array, w_bits: int = 8) -> jax.Array:
     """Y = (Xq @ Wq^T) * sx * sw.  xq: (M, K) int8; wq_packed:
-    (N, K*w_bits/8) int8; sw: (N,); sx: scalar. Returns (M, N) f32."""
+    (N, ceil(K*w_bits/8)) int8 in the planar layout of
+    :func:`pack_weights`; sw: (N,); sx: scalar. Returns (M, N) f32."""
     m, k = xq.shape
-    n = wq_packed.shape[0]
+    n, kp = wq_packed.shape
     per = 8 // w_bits
+    # split x into the same planes as the packed weight: plane i holds
+    # columns [i*kp, (i+1)*kp) (K zero-padded up to per*kp)
+    planes = _pad_to(xq, per * kp, 1).reshape(m, per, kp).transpose(1, 0, 2)
     bm = min(_k.DEFAULT_BM, max(8, m))
     bn = min(_k.DEFAULT_BN, max(128, n))
-    bk = min(_k.DEFAULT_BK, max(128, k))
-    xp = _pad_to(_pad_to(xq, bm, 0), bk, 1)
-    wp = _pad_to(_pad_to(wq_packed, bn, 0), bk // per, 1)
+    bk = min(_k.DEFAULT_BK, max(128, kp))
+    xp = _pad_to(_pad_to(planes, bm, 1), bk, 2)
+    wp = _pad_to(_pad_to(wq_packed, bn, 0), bk, 1)
     swp = _pad_to(sw.reshape(1, -1), bn, 1)
     out = _k.quant_matmul_fwd(
         xp, wp, swp, sx.reshape(1, 1).astype(jnp.float32), w_bits=w_bits,

@@ -7,19 +7,21 @@ import numpy as np
 
 
 def pack_weights(wq: np.ndarray, bits: int) -> np.ndarray:
-    """Pack signed `bits`-bit integers (N, K) little-endian into int8
-    (N, K*bits/8). K must be a multiple of 8/bits."""
+    """Pack signed `bits`-bit integers (N, K) into int8 (N, K*bits/8) in
+    the kernel's planar layout: byte ``j`` holds the values at
+    ``k = j + i * K*bits/8``, value ``i`` in bits ``[bits*i, bits*(i+1))``.
+    K must be a multiple of 8/bits."""
     if bits == 8:
         return wq.astype(np.int8)
     per = 8 // bits
     n, k = wq.shape
     assert k % per == 0
     u = (wq.astype(np.int32) & ((1 << bits) - 1)).astype(np.uint8)
-    u = u.reshape(n, k // per, per)
+    u = u.reshape(n, per, k // per)
     out = np.zeros((n, k // per), np.uint8)
     for i in range(per):
-        out |= u[:, :, i] << (bits * i)
-    return out.astype(np.int8)
+        out |= u[:, i, :] << (bits * i)
+    return out.view(np.int8)
 
 
 def quant_matmul_ref(xq: jax.Array, wq: jax.Array, sw: jax.Array,

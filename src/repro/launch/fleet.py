@@ -41,6 +41,7 @@ import os
 import jax
 
 from repro.configs import registry
+from repro.launch import compile_cache
 from repro.models import lm
 from repro.serve import engine
 from repro import fleet as fleet_mod
@@ -172,6 +173,7 @@ def main():
     ap.add_argument("--report", default=None, metavar="PATH",
                     help="write the SLO report as JSON to PATH")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = registry.get(args.arch)
     params = lm.init_params(cfg, jax.random.key(0))

@@ -25,6 +25,7 @@ import jax
 import numpy as np
 
 from repro.configs import registry
+from repro.launch import compile_cache
 from repro.models import lm
 from repro.serve import engine
 from repro.serve.sampling import SamplingParams
@@ -78,6 +79,7 @@ def main():
                     help="enable observability and write the per-request "
                          "lifecycle trace as JSON lines to PATH")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = registry.get(args.arch)
     params = lm.init_params(cfg, jax.random.key(0))

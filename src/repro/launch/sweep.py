@@ -25,6 +25,7 @@ import json
 
 from repro import obs as obs_mod
 from repro import sweep as sweep_mod
+from repro.launch import compile_cache
 
 
 def build_spec(args) -> sweep_mod.SweepSpec:
@@ -85,6 +86,7 @@ def main(argv=None):
     ap.add_argument("--report", default=None, metavar="PATH",
                     help="write the sweep summary as JSON")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     spec = build_spec(args)
     obs = obs_mod.Observability() if (args.metrics or args.trace) \

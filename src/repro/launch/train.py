@@ -32,6 +32,7 @@ from repro.checkpoint.checkpoint import CheckpointManager  # noqa: E402
 from repro.configs import registry                      # noqa: E402
 from repro.data import synthetic                        # noqa: E402
 from repro.distributed import sharding                  # noqa: E402
+from repro.launch import compile_cache                  # noqa: E402
 from repro.launch import mesh as meshlib                # noqa: E402
 from repro.launch import steps as steps_lib             # noqa: E402
 from repro.models import lm                             # noqa: E402
@@ -51,6 +52,7 @@ def main():
     ap.add_argument("--production-mesh", action="store_true",
                     help="use the 16x16 mesh (needs 256 devices)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = registry.get(args.arch)
     n_dev = len(jax.devices())

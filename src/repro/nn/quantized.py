@@ -51,8 +51,10 @@ def pack_channelwise(w: np.ndarray, channel_bits: np.ndarray,
     from ``channel_bits``.
 
     Returns ``(packed, perm, kept)`` where ``packed`` is
-    ``[(bits, wq_packed (Ni, C_in*bits/8) int8, scales (Ni,) f32), ...]``
-    in ascending-bits order and ``kept`` counts the non-pruned channels.
+    ``[(bits, wq_packed (Ni, ceil(C_in*bits/8)) int8, scales (Ni,) f32),
+    ...]`` in ascending-bits order -- ``C_in`` is zero-padded to a whole
+    number of bytes, then packed in ``quant_matmul``'s planar layout --
+    and ``kept`` counts the non-pruned channels.
     A fully-pruned layer yields ``packed == []`` and ``kept == 0``.
     """
     if perm is None:
@@ -60,18 +62,23 @@ def pack_channelwise(w: np.ndarray, channel_bits: np.ndarray,
             {"gamma": {"l": channel_bits}})["l"]
     w_sorted = np.asarray(w)[perm]
     bits_sorted = np.asarray(channel_bits)[perm]
+    # the scale is per row, so integerizing every row at each width and
+    # keeping that width's rows gives the same bytes as integerizing the
+    # group alone -- and one array shape per layer shape, not one per
+    # group size, for the eager ops to compile
+    w_dev = jnp.asarray(w_sorted)
     packed = []
     for b in sorted(set(int(x) for x in bits_sorted if x > 0)):
-        rows = w_sorted[bits_sorted == b]
-        qi, scale = quantizers.integerize_weights(jnp.asarray(rows), b, 0)
-        k = rows.shape[1]
+        sel = bits_sorted == b
+        qi, scale = quantizers.integerize_weights(w_dev, b, 0)
+        k = w_sorted.shape[1]
         per = 8 // b
         pad = (-k) % per
-        qi_np = np.asarray(qi)
+        qi_np = np.asarray(qi)[sel]
         if pad:
             qi_np = np.pad(qi_np, ((0, 0), (0, pad)))
         packed.append((b, jnp.asarray(qops.pack_weights(qi_np, b)),
-                       jnp.asarray(scale[:, 0])))
+                       jnp.asarray(np.asarray(scale)[sel, 0])))
     kept = int(np.sum(bits_sorted > 0))
     return packed, perm, kept
 

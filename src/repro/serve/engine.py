@@ -77,7 +77,9 @@ def apply_plan(cfg, params, plan, strict: bool = True):
                     and "gamma" in tnode and tnode["w"].ndim == 3):
                 group = f"{path}.sb{j}"
                 if group in plan.channel_bits:
-                    w = np.asarray(pnode["w"], np.float32)[j]   # (K, N)
+                    # index on device first: the whole (nsb, K, N) stack
+                    # would cross to the host once per super-block
+                    w = np.asarray(pnode["w"][j], np.float32)   # (K, N)
                     return {"w": nnq.PackedLinear.from_dense(
                         w, plan.channel_bits[group],
                         perm=plan.permutations[group])}

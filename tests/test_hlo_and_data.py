@@ -102,6 +102,16 @@ class TestSyntheticData:
         x3, _ = synthetic.class_batch(synthetic.CIFAR10_LIKE, 6, 16, 0)
         assert not np.allclose(np.asarray(x1), np.asarray(x3))
 
+    def test_templates_do_not_depend_on_process_hash(self, monkeypatch):
+        """Python's str hash is salted per process; the data (and the
+        compiled search step it is baked into) must not be."""
+        spec = synthetic.CIFAR10_LIKE
+        before = np.asarray(synthetic._templates(spec))
+        monkeypatch.setattr(synthetic, "hash", lambda _: 12345,
+                            raising=False)
+        np.testing.assert_array_equal(
+            np.asarray(synthetic._templates(spec)), before)
+
     def test_class_structure_learnable(self):
         """Same-class samples are closer to their template than to others
         (so the dataset is actually learnable)."""

@@ -101,9 +101,14 @@ class TestQuantMatmul:
         for bits in (2, 4, 8):
             lim = 2 ** (bits - 1)
             rng = np.random.default_rng(0)
-            wq = rng.integers(-lim + 1, lim, size=(5, 24)).astype(np.int8)
+            # the full signed range, -2^(b-1) included, exercises the
+            # sign extension of every plane
+            wq = rng.integers(-lim, lim, size=(5, 24)).astype(np.int8)
             packed = qref.pack_weights(wq, bits)
-            unpacked = np.asarray(qk._unpack(jnp.asarray(packed), bits))
+            assert packed.shape == (5, 24 * bits // 8)
+            # planar layout: plane i holds columns [i*Kp, (i+1)*Kp)
+            planes = qk._unpack(jnp.asarray(packed), bits)
+            unpacked = np.concatenate([np.asarray(p) for p in planes], 1)
             np.testing.assert_array_equal(unpacked, wq)
 
     def test_quantized_linear_errors_bounded(self):
