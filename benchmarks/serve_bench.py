@@ -10,11 +10,7 @@ cache backend's peak memory) and prints the orchestrator's
 The dense-vs-paged pairs run the SAME streaming mixed-prompt-length
 workload and must produce identical tokens (asserted); the paged rows
 additionally record peak cache bytes, which scale with live tokens
-instead of the dense ``max_batch * max_len`` pin.  Every row carries the
-per-step decode latency split (``gather_us_per_step`` -- assembling the
-step inputs from the cache backend -- vs. ``step_us_per_step`` -- the
-jitted decode itself), which is where the device-resident block tables
-show up: paged gather no longer rebuilds host tables per step.
+instead of the dense ``max_batch * max_len`` pin.
 
 Since PR 10 every row also carries a **prefill-latency split**
 (``prefill_ms_p50/p95/p99``: tracer-measured admitted->prefilled wall
@@ -96,8 +92,8 @@ def make_requests(cfg, n, prompt_lens, tokens, gap):
 
 def _row_from(stats, name, cache, wall, out, plan):
     """Build one result row from a serve() stats snapshot.  `stats` must
-    come from the SAME repeat as `wall` (the best one), or the per-step
-    latency split would describe a different run than the wall time."""
+    come from the SAME repeat as `wall` (the best one), or the row would
+    describe a different run than the wall time."""
     tokens = int(sum(len(r) for r in out.values()))
     mem = stats["memory"]
     row = {
@@ -108,8 +104,6 @@ def _row_from(stats, name, cache, wall, out, plan):
         "tok_per_s": round(tokens / wall, 2),
         "decode_steps": stats["decode_steps"],
         "preemptions": stats["preemptions"],
-        "gather_us_per_step": stats["gather_us_per_step"],
-        "step_us_per_step": stats["step_us_per_step"],
         "peak_cache_bytes": mem["peak_cache_bytes"]
         if cache == "paged" else mem["cache_bytes"],
         "plan": None,
@@ -398,13 +392,9 @@ def main(argv=None):
                       f"paged_prefill_ms_p50="
                       f"{brow['paged_prefill_ms_p50']}")
             print(f"serve/{name},{row['wall_s'] * 1e6:.0f},"
-                  f"tok_per_s={row['tok_per_s']},"
-                  f"gather_us={row['gather_us_per_step']},"
-                  f"step_us={row['step_us_per_step']}")
+                  f"tok_per_s={row['tok_per_s']}")
             print(f"serve/{prow['name']},{prow['wall_s'] * 1e6:.0f},"
                   f"tok_per_s={prow['tok_per_s']},"
-                  f"gather_us={prow['gather_us_per_step']},"
-                  f"step_us={prow['step_us_per_step']},"
                   f"peak_cache_bytes={prow['peak_cache_bytes']},"
                   f"dense_bytes={prow['dense_equivalent_bytes']}")
             continue
@@ -412,9 +402,7 @@ def main(argv=None):
                                args.max_len, args.max_batch)
         results.append(row)
         print(f"serve/{name},{row['wall_s'] * 1e6:.0f},"
-              f"tok_per_s={row['tok_per_s']},"
-              f"gather_us={row['gather_us_per_step']},"
-              f"step_us={row['step_us_per_step']}")
+              f"tok_per_s={row['tok_per_s']}")
 
     report = {
         "benchmark": "serve",

@@ -189,4 +189,5 @@ def paged_attention_fwd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(tables.astype(jnp.int32), pos.astype(jnp.int32), q, k_pool, v_pool)

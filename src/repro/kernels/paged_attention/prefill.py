@@ -217,6 +217,7 @@ def paged_prefill_fwd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, s, h, d), q.dtype),
         interpret=interpret,
+        name="paged_prefill_attention",
     )(tables.astype(jnp.int32), lens.astype(jnp.int32), q, k_pool, v_pool)
 
 

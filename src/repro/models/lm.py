@@ -307,30 +307,33 @@ def _layer_apply(cfg, spec: LayerSpec, p, x, *, mode, cache, pos,
         if st is not None:
             new_cache["mamba"] = st
     else:
-        y, kv = blocks.attention_layer(
-            p["mixer"], h, cfg, kind=mixer_kind[spec.mixer],
-            mode=("train" if mode == "train" else mode),
-            cache=None if cache is None else cache.get("kv"),
-            pos=pos, effective_w=getw, tables=tables)
+        with jax.named_scope("attn"):
+            y, kv = blocks.attention_layer(
+                p["mixer"], h, cfg, kind=mixer_kind[spec.mixer],
+                mode=("train" if mode == "train" else mode),
+                cache=None if cache is None else cache.get("kv"),
+                pos=pos, effective_w=getw, tables=tables)
         if kv is not None:
             new_cache["kv"] = kv
     x = x + y
     if spec.cross and enc_out is not None:
         hc = blocks.rmsnorm(x, p["norm_cross"], cfg.norm_eps)
-        yc, ckv = blocks.attention_layer(
-            p["cross"], hc, cfg, kind="cross",
-            mode=("train" if mode == "train" else mode),
-            cache=None if cache is None else cache.get("cross_kv"),
-            pos=pos, kv_input=enc_out)
+        with jax.named_scope("attn"):
+            yc, ckv = blocks.attention_layer(
+                p["cross"], hc, cfg, kind="cross",
+                mode=("train" if mode == "train" else mode),
+                cache=None if cache is None else cache.get("cross_kv"),
+                pos=pos, kv_input=enc_out)
         if ckv is not None:
             new_cache["cross_kv"] = ckv
         x = x + yc
     if spec.ffn is not None:
         h2 = blocks.rmsnorm(x, p["norm2"], cfg.norm_eps)
-        if spec.ffn == "moe":
-            y2 = blocks.moe_layer(p["ffn"], h2, cfg, effective_w=getw)
-        else:
-            y2 = blocks.ffn_swiglu(p["ffn"], h2, effective_w=getw)
+        with jax.named_scope("ffn"):
+            if spec.ffn == "moe":
+                y2 = blocks.moe_layer(p["ffn"], h2, cfg, effective_w=getw)
+            else:
+                y2 = blocks.ffn_swiglu(p["ffn"], h2, effective_w=getw)
         x = x + y2
     return x, (new_cache or None)
 
@@ -389,24 +392,29 @@ def _run_stack_unrolled(cfg, pattern, per_sb_params, x, *, mode, caches,
     :class:`~repro.nn.quantized.PackedLinear` buffers have layer-dependent
     shapes (different per-precision channel counts), so they cannot be
     stacked for a ``lax.scan``.  Caches keep the stacked ``(nsb, ...)``
-    layout of :func:`init_caches`."""
+    layout of :func:`init_caches`: each block's cache is sliced out of
+    the stack and the stack is rebuilt after the last block, both under
+    the ``kv_pool`` scope; each layer runs under ``layer{n}``."""
     per_sb_caches = []
     for j, blk_params in enumerate(per_sb_params):
-        blk_cache = None if caches is None else \
-            jax.tree.map(lambda a: a[j], caches)
+        with jax.named_scope("kv_pool"):
+            blk_cache = None if caches is None else \
+                jax.tree.map(lambda a: a[j], caches)
         new_caches = {}
         for i, spec in enumerate(pattern):
             cache_i = None if blk_cache is None else blk_cache.get(f"l{i}")
-            x, nc = _layer_apply(cfg, spec, blk_params[f"l{i}"], x,
-                                 mode=mode, cache=cache_i, pos=pos,
-                                 enc_out=enc_out, getw=getw,
-                                 tables=tables)
+            with jax.named_scope(f"layer{j * len(pattern) + i}"):
+                x, nc = _layer_apply(cfg, spec, blk_params[f"l{i}"], x,
+                                     mode=mode, cache=cache_i, pos=pos,
+                                     enc_out=enc_out, getw=getw,
+                                     tables=tables)
             if nc is not None:
                 new_caches[f"l{i}"] = nc
         per_sb_caches.append(new_caches or None)
     if any(c is not None for c in per_sb_caches):
-        stacked = jax.tree.map(lambda *xs: jnp.stack(xs, axis=0),
-                               *per_sb_caches)
+        with jax.named_scope("kv_pool"):
+            stacked = jax.tree.map(lambda *xs: jnp.stack(xs, axis=0),
+                                   *per_sb_caches)
     else:
         stacked = None
     return x, stacked
@@ -504,11 +512,12 @@ def forward(cfg: ArchConfig, params, batch, *, mode: str = "train",
         else:
             x = jax.lax.dynamic_slice_in_dim(x, jnp.asarray(last_pos), 1,
                                              axis=1)
-    logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"]["w"].astype(
-        jnp.bfloat16))
-    logits = sharding.constrain(logits, "batch", None, "vocab")
-    if cfg.final_softcap > 0:
-        logits = blocks.softcap(logits, cfg.final_softcap)
+    with jax.named_scope("lm_head"):
+        logits = jnp.einsum("bsd,dv->bsv", x,
+                            params["lm_head"]["w"].astype(jnp.bfloat16))
+        logits = sharding.constrain(logits, "batch", None, "vocab")
+        if cfg.final_softcap > 0:
+            logits = blocks.softcap(logits, cfg.final_softcap)
     return logits, new_caches
 
 

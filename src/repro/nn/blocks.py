@@ -251,6 +251,9 @@ def attention_layer(p: dict, x: jax.Array, cfg, *, kind: str = "full",
     mode="prefill" the produced K/V are returned as the new cache; for
     mode="decode" the token's K/V are written at `pos`.
 
+    The cache write runs under the ``kv_pool`` named scope (op metadata
+    only), so a profiler trace attributes it to KV pool handling.
+
     tables (decode + prefill): (B, P) int32 per-slot block tables of a
     :class:`~repro.serve.cache.PagedCache` -- cache["k"/"v"] are then
     page POOLS of shape (n_pages + 1, page_size, Hkv, D) and attention
@@ -310,12 +313,13 @@ def attention_layer(p: dict, x: jax.Array, cfg, *, kind: str = "full",
             pos_b = jnp.broadcast_to(posn, (b,)) if posn.ndim == 0 \
                 else posn                                # (B,)
             rows = jnp.arange(b)
-            phys = tables[rows, pos_b // page_size]      # (B,)
-            off = pos_b % page_size
-            ck = cache["k"].at[phys, off].set(kk[:, 0].astype(
-                cache["k"].dtype))
-            cv = cache["v"].at[phys, off].set(vv[:, 0].astype(
-                cache["v"].dtype))
+            with jax.named_scope("kv_pool"):
+                phys = tables[rows, pos_b // page_size]      # (B,)
+                off = pos_b % page_size
+                ck = cache["k"].at[phys, off].set(kk[:, 0].astype(
+                    cache["k"].dtype))
+                cv = cache["v"].at[phys, off].set(vv[:, 0].astype(
+                    cache["v"].dtype))
             new_cache = {"k": ck, "v": cv}
             out = paged_decode_attention(q, ck, cv, tables, pos_b,
                                          window=window, chunked=chunked,
@@ -324,15 +328,16 @@ def attention_layer(p: dict, x: jax.Array, cfg, *, kind: str = "full",
             if cache is not None:
                 kk = kk.astype(cache["k"].dtype)
                 vv = vv.astype(cache["v"].dtype)
-                if posn.ndim == 0:
-                    ck = jax.lax.dynamic_update_slice_in_dim(cache["k"],
-                                                             kk, posn, 1)
-                    cv = jax.lax.dynamic_update_slice_in_dim(cache["v"],
-                                                             vv, posn, 1)
-                else:
-                    rows = jnp.arange(b)
-                    ck = cache["k"].at[rows, posn].set(kk[:, 0])
-                    cv = cache["v"].at[rows, posn].set(vv[:, 0])
+                with jax.named_scope("kv_pool"):
+                    if posn.ndim == 0:
+                        ck = jax.lax.dynamic_update_slice_in_dim(
+                            cache["k"], kk, posn, 1)
+                        cv = jax.lax.dynamic_update_slice_in_dim(
+                            cache["v"], vv, posn, 1)
+                    else:
+                        rows = jnp.arange(b)
+                        ck = cache["k"].at[rows, posn].set(kk[:, 0])
+                        cv = cache["v"].at[rows, posn].set(vv[:, 0])
             else:
                 ck, cv = kk, vv
             new_cache = {"k": ck, "v": cv}
@@ -354,17 +359,18 @@ def attention_layer(p: dict, x: jax.Array, cfg, *, kind: str = "full",
             # tokens; garbage past a partial page's tail never exists.
             page_size = cache["k"].shape[1]
             lens_b = jnp.broadcast_to(jnp.asarray(pos), (b,))       # (B,)
-            pg = jnp.minimum(positions // page_size,
-                             tables.shape[1] - 1)                   # (S,)
-            phys = tables[jnp.arange(b)[:, None], pg[None, :]]      # (B,S)
-            phys = jnp.where(positions[None, :] < lens_b[:, None],
-                             phys, cache["k"].shape[0])             # OOB
-            off = jnp.broadcast_to(positions[None, :] % page_size,
-                                   (b, s))
-            ck = cache["k"].at[phys, off].set(
-                kk.astype(cache["k"].dtype), mode="drop")
-            cv = cache["v"].at[phys, off].set(
-                vv.astype(cache["v"].dtype), mode="drop")
+            with jax.named_scope("kv_pool"):
+                pg = jnp.minimum(positions // page_size,
+                                 tables.shape[1] - 1)               # (S,)
+                phys = tables[jnp.arange(b)[:, None], pg[None, :]]  # (B,S)
+                phys = jnp.where(positions[None, :] < lens_b[:, None],
+                                 phys, cache["k"].shape[0])         # OOB
+                off = jnp.broadcast_to(positions[None, :] % page_size,
+                                       (b, s))
+                ck = cache["k"].at[phys, off].set(
+                    kk.astype(cache["k"].dtype), mode="drop")
+                cv = cache["v"].at[phys, off].set(
+                    vv.astype(cache["v"].dtype), mode="drop")
             new_cache = {"k": ck, "v": cv}
             out = paged_prefill_attention(q, ck, cv, tables, lens_b,
                                           window=window, chunked=chunked,

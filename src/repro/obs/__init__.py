@@ -8,8 +8,10 @@ engine additionally records per-request lifecycle events through a
 
 Everything is host-side and dependency-free; with the registry
 disabled each instrumentation site costs a no-op method call, and no
-site lives inside jitted code.  See ``src/repro/obs/README.md`` for
-the metric catalog and exporter formats.
+site lives inside jitted code.  :func:`span` puts host spans on the
+profiler's clock (JAX is imported when a span opens, not here).  See
+``src/repro/obs/README.md`` for the metric catalog, the spans and the
+exporter formats.
 """
 from .registry import (LATENCY_BUCKETS_S, Counter, Gauge, Histogram,
                        MetricsRegistry)
@@ -17,6 +19,7 @@ from .tracing import (EVENT_KINDS, FAULT_TERMINAL_KINDS, SWEEP_KINDS,
                       TERMINAL_KINDS, RequestTracer, TraceEvent)
 from .exporters import (percentiles, run_summary, to_prometheus,
                         trace_to_jsonl, write_prometheus, write_trace)
+from .spans import span
 
 
 class Observability:
@@ -50,5 +53,5 @@ __all__ = [
     "MetricsRegistry", "EVENT_KINDS", "FAULT_TERMINAL_KINDS",
     "SWEEP_KINDS", "TERMINAL_KINDS", "RequestTracer", "TraceEvent",
     "Observability", "percentiles", "run_summary", "to_prometheus",
-    "trace_to_jsonl", "write_prometheus", "write_trace",
+    "trace_to_jsonl", "write_prometheus", "write_trace", "span",
 ]

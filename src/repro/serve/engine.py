@@ -30,7 +30,6 @@ that.
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import jax
 import jax.numpy as jnp
@@ -40,7 +39,7 @@ from repro.kernels.paged_attention import ops as paged_ops
 from repro.launch import steps
 from repro.models import lm
 from repro.nn import quantized as nnq
-from repro.obs import run_summary
+from repro.obs import run_summary, span
 from repro.serve import cache as cache_mod
 from repro.serve.sampling import (SamplingParams, batch_need_top_k,
                                   make_rng, sample_token,
@@ -257,11 +256,12 @@ class InferenceServer:
             logits, caches = lm.decode_step(
                 cfg, params, tokens, caches, pos,
                 tables=_live_tables(tables, width))
-            row = logits[:, -1, :vocab]
-            next_tok = sample_tokens_device(
-                row, temps, topks, seeds, uids, tidx,
-                need_top_k=need_top_k)
-            return next_tok, caches, jnp.isnan(row).any()
+            with jax.named_scope("sample"):
+                row = logits[:, -1, :vocab]
+                next_tok = sample_tokens_device(
+                    row, temps, topks, seeds, uids, tidx,
+                    need_top_k=need_top_k)
+                return next_tok, caches, jnp.isnan(row).any()
 
         self._decode_sample = jax.jit(decode_sample, donate_argnums=(2,),
                                       static_argnums=(10, 11))
@@ -271,9 +271,10 @@ class InferenceServer:
             logits, caches = lm.decode_step(
                 cfg, params, tokens, caches, pos,
                 tables=_live_tables(tables, width))
-            row = logits[:, -1, :vocab].astype(jnp.float32)
-            next_tok = jnp.argmax(row, axis=-1)
-            return next_tok.astype(jnp.int32), caches, jnp.isnan(row).any()
+            with jax.named_scope("sample"):
+                row = logits[:, -1, :vocab].astype(jnp.float32)
+                next_tok = jnp.argmax(row, axis=-1).astype(jnp.int32)
+                return next_tok, caches, jnp.isnan(row).any()
 
         self._decode_greedy = jax.jit(decode_greedy, donate_argnums=(2,),
                                       static_argnums=(5,))
@@ -281,14 +282,14 @@ class InferenceServer:
         # crossing an already-paid host boundary, so corrupted (e.g.
         # NaN-poisoned-plan) logits are caught before a garbage token
         # can enter a client stream
-        self._sample = jax.jit(
-            lambda lg, temps, topks, seeds, uids, tidx, need_top_k:
-            (sample_tokens_device(lg[:, :vocab], temps, topks, seeds,
-                                  uids, tidx, need_top_k=need_top_k),
-             jnp.isnan(lg[:, :vocab]).any()),
-            static_argnums=(6,))
-        # per-step decode latency split: [gather_s, step_s, n_steps]
-        self._step_timing = [0.0, 0.0, 0]
+        def sample(lg, temps, topks, seeds, uids, tidx, need_top_k):
+            with jax.named_scope("sample"):
+                return (sample_tokens_device(lg[:, :vocab], temps, topks,
+                                             seeds, uids, tidx,
+                                             need_top_k=need_top_k),
+                        jnp.isnan(lg[:, :vocab]).any())
+
+        self._sample = jax.jit(sample, static_argnums=(6,))
         # session state (see the "serving" section): None between runs
         self._sched = None
         self._now = 0
@@ -343,10 +344,14 @@ class InferenceServer:
                 jnp.asarray([uid], jnp.int32),
                 jnp.asarray([tidx], jnp.int32),
                 0 < sp.top_k < self.cfg.vocab)
-            if bool(np.asarray(bad)):
+            with span("serve.device_wait"):
+                bad = bool(np.asarray(bad))
+            if bad:
                 self._flag_nan()
-            return int(np.asarray(tok)[0])
-        row = np.asarray(logits_last.astype(jnp.float32))[0]
+            with span("serve.device_wait"):
+                return int(np.asarray(tok)[0])
+        with span("serve.device_wait"):
+            row = np.asarray(logits_last.astype(jnp.float32))[0]
         vrow = row[: self.cfg.vocab]
         if np.isnan(vrow).any():
             self._flag_nan()
@@ -388,7 +393,6 @@ class InferenceServer:
         self._sched = Scheduler(self.max_batch, self.max_len,
                                 tracer=tracer)
         self.backend.reset()
-        self._step_timing = [0.0, 0.0, 0]
         self._now = 0
         self._n_steps = 0
         self._n_admitted = 0
@@ -445,7 +449,8 @@ class InferenceServer:
                     "Requests admitted into a decode slot",
                     labels=("resumed",)).inc(
                     resumed="true" if resumed else "false")
-            logits = self._run_prefill(backend, handle, tokens_np)
+            with span("serve.prefill", uid=req.uid):
+                logits = self._run_prefill(backend, handle, tokens_np)
             if tracer is not None:
                 tracer.event(req.uid, "prefilled", n=tokens_np.size,
                              pages_held=len(handle.pages), slot=slot)
@@ -464,7 +469,8 @@ class InferenceServer:
             self._n_admitted += 1
             if entry.resume is None:
                 rng = make_rng(req.sampling, req.uid)
-                tok = self._sample_first(logits, req, req.uid, 0, rng)
+                with span("serve.sample_first", uid=req.uid):
+                    tok = self._sample_first(logits, req, req.uid, 0, rng)
                 st = SlotState(request=req, slot=slot,
                                pos=int(tokens_np.size),
                                remaining=req.sampling.max_tokens - 1,
@@ -472,8 +478,9 @@ class InferenceServer:
                                order=self._n_admitted, handle=handle)
             else:       # preempted request: continue its exact stream
                 st = entry.resume
-                tok = self._sample_first(logits, req, req.uid,
-                                         len(st.out), st.rng)
+                with span("serve.sample_first", uid=req.uid):
+                    tok = self._sample_first(logits, req, req.uid,
+                                             len(st.out), st.rng)
                 st.slot = slot
                 st.pos = int(tokens_np.size)
                 st.out.append(tok)
@@ -505,10 +512,15 @@ class InferenceServer:
         """One admission + batched-decode round of the open session."""
         if self._sched is None:
             raise RuntimeError("no open session; call begin() first")
+        with span("serve.step"):
+            return self._step()
+
+    def _step(self) -> StepResult:
         sched, backend = self._sched, self.backend
         tracer = self.obs.tracer if self.obs is not None else None
         fin0 = len(sched.finished)
-        admitted = self._admit()
+        with span("serve.admit"):
+            admitted = self._admit()
         # every admission yields one token (sampled from the prefill
         # logits), so admitted uids are producers this step
         produced = {}
@@ -541,37 +553,44 @@ class InferenceServer:
                 return StepResult(admitted=admitted, produced=produced,
                                   finished=list(sched.finished)[fin0:],
                                   nan=True)
-            survivors = []
-            for st in active:
-                st.pos += 1
-                tok = next_toks[st.slot]
-                st.out.append(tok)
-                st.last_token = tok
-                st.remaining -= 1
-                produced[st.request.uid] = len(st.out)
-                if tracer is not None:
-                    tracer.event(st.request.uid, "decode", n=len(st.out),
-                                 pages_held=len(st.handle.pages),
-                                 slot=st.slot)
-                if st.remaining <= 0:
-                    backend.free(st.handle)
-                    sched.complete(st.slot)
-                elif st.pos >= self.max_len:
-                    st.truncated = True
-                    backend.free(st.handle)
-                    sched.complete(st.slot)
-                else:
-                    survivors.append(st)
-            # page-backing AFTER every slot recorded its token: a
-            # preemption victim then always requeues with its full
-            # sampled stream (resume re-derives nothing)
-            for st in survivors:
-                if sched.slots[st.slot] is st:   # not already preempted
-                    self._append_or_preempt(sched, backend, st)
+            with span("serve.bookkeep"):
+                self._bookkeep(active, next_toks, produced, tracer)
             self._now += 1
         finished = list(sched.finished)[fin0:]
         return StepResult(admitted=admitted, produced=produced,
                           finished=finished, idle=idle)
+
+    def _bookkeep(self, active, next_toks, produced, tracer):
+        """Record each slot's decoded token, free the finished, and back
+        the survivors' next cache write."""
+        sched, backend = self._sched, self.backend
+        survivors = []
+        for st in active:
+            st.pos += 1
+            tok = next_toks[st.slot]
+            st.out.append(tok)
+            st.last_token = tok
+            st.remaining -= 1
+            produced[st.request.uid] = len(st.out)
+            if tracer is not None:
+                tracer.event(st.request.uid, "decode", n=len(st.out),
+                             pages_held=len(st.handle.pages),
+                             slot=st.slot)
+            if st.remaining <= 0:
+                backend.free(st.handle)
+                sched.complete(st.slot)
+            elif st.pos >= self.max_len:
+                st.truncated = True
+                backend.free(st.handle)
+                sched.complete(st.slot)
+            else:
+                survivors.append(st)
+        # page-backing AFTER every slot recorded its token: a
+        # preemption victim then always requeues with its full
+        # sampled stream (resume re-derives nothing)
+        for st in survivors:
+            if sched.slots[st.slot] is st:   # not already preempted
+                self._append_or_preempt(sched, backend, st)
 
     def cancel(self, uid: int, reason: str = "cancelled"):
         """Cancel a queued or in-flight request, freeing its cache pages
@@ -617,7 +636,6 @@ class InferenceServer:
         sched = self._sched
         if sched is None:
             raise RuntimeError("no open session; call begin() first")
-        gather_s, step_s, timed = self._step_timing
         reasons = [r for r, _ in self._cancelled.values()]
         self.stats = {"decode_steps": self._n_steps,
                       "admitted": self._n_admitted,
@@ -626,13 +644,6 @@ class InferenceServer:
                                        for s in sched.finished.values()),
                       "cancelled": reasons.count("cancelled"),
                       "timeouts": reasons.count("timeout"),
-                      # per-step decode latency split: assembling the
-                      # step's inputs from the backend (gather + device
-                      # tables) vs. running the jitted step itself
-                      "gather_us_per_step": round(
-                          gather_s / timed * 1e6, 2) if timed else 0.0,
-                      "step_us_per_step": round(
-                          step_s / timed * 1e6, 2) if timed else 0.0,
                       "memory": self.backend.memory_report()}
         self.backend.publish_metrics()
         out = {uid: np.asarray(s.out, np.int32)
@@ -748,83 +759,64 @@ class InferenceServer:
 
     def _decode_active(self, active) -> dict:
         """One batched decode step; returns {slot: sampled token id}."""
-        tokens = np.zeros((self.max_batch, 1), np.int32)
-        pos = np.zeros((self.max_batch,), np.int32)
-        for st in active:
-            tokens[st.slot, 0] = st.last_token
-            pos[st.slot] = st.pos
-        t0 = time.perf_counter()
-        caches = self.backend.gather()
-        tables = self.backend.device_tables()
-        width = self._live_width(active)
-        t1 = time.perf_counter()
-        step_end = None      # host-sampling path stamps the step's end
-        path = "host"        # which decode callable ran (metrics label)
-        try:                 # itself, excluding its python sample loop
-            if self.sample_on_device and all(
-                    st.request.sampling.greedy for st in active):
-                # every active row is greedy: argmax decode, none of the
-                # sort/Gumbel machinery (bit-identical to the full sampler)
-                path = "greedy"
-                next_tok, caches, bad = self._decode_greedy(
-                    self.params, {"tokens": jnp.asarray(tokens)}, caches,
-                    tables, jnp.asarray(pos), width)
-                self.backend.commit(caches)
-                if bool(np.asarray(bad)):
+        # which decode callable runs (the metrics label): when every
+        # active row is greedy, the argmax decode, none of the sort/Gumbel
+        # machinery (bit-identical to the full sampler)
+        if not self.sample_on_device:
+            path = "host"
+        elif all(st.request.sampling.greedy for st in active):
+            path = "greedy"
+        else:
+            path = "sample"
+        with span("serve.decode.inputs"):
+            tokens = np.zeros((self.max_batch, 1), np.int32)
+            pos = np.zeros((self.max_batch,), np.int32)
+            for st in active:
+                tokens[st.slot, 0] = st.last_token
+                pos[st.slot] = st.pos
+            caches = self.backend.gather()
+            tables = self.backend.device_tables()
+            width = self._live_width(active)
+            if path == "sample":
+                sampling = self._sampling_inputs(active)
+        try:
+            if path == "host":
+                with span("serve.decode.launch"):
+                    logits, caches = self._decode(
+                        self.params, {"tokens": jnp.asarray(tokens)},
+                        caches, tables, jnp.asarray(pos), width)
+                    self.backend.commit(caches)
+                with span("serve.device_wait"):
+                    rows = np.asarray(logits.astype(jnp.float32))[
+                        :, -1, : self.cfg.vocab]
+                if any(np.isnan(rows[st.slot]).any() for st in active):
                     self._flag_nan()
-                ids = np.asarray(next_tok)
-                return {st.slot: int(ids[st.slot]) for st in active}
-            if self.sample_on_device:
-                path = "sample"
-                temps = np.zeros(self.max_batch, np.float32)
-                topks = np.zeros(self.max_batch, np.int32)
-                seeds = np.zeros(self.max_batch, np.int32)
-                uids = np.zeros(self.max_batch, np.int32)
-                tidx = np.zeros(self.max_batch, np.int32)
-                for st in active:
-                    sp = st.request.sampling
-                    temps[st.slot] = sp.temperature
-                    topks[st.slot] = sp.top_k
-                    seeds[st.slot] = sp.seed
-                    uids[st.slot] = st.request.uid
-                    tidx[st.slot] = len(st.out)
-                # trace-time flag: rows that truncate need the full-vocab
-                # sort; a pure-temperature batch skips it entirely
-                need_top_k = batch_need_top_k(
-                    [st.request.sampling for st in active],
-                    self.cfg.vocab, self._reg)
-                next_tok, caches, bad = self._decode_sample(
-                    self.params, {"tokens": jnp.asarray(tokens)}, caches,
-                    tables, jnp.asarray(pos), jnp.asarray(temps),
-                    jnp.asarray(topks), jnp.asarray(seeds),
-                    jnp.asarray(uids), jnp.asarray(tidx), need_top_k,
-                    width)
+                    # don't sample from poisoned rows (the host
+                    # sampler's softmax would propagate the NaN); step()
+                    # discards the step's tokens anyway
+                    return {st.slot: 0 for st in active}
+                return {st.slot: sample_token(rows[st.slot],
+                                              st.request.sampling, st.rng)
+                        for st in active}
+            with span("serve.decode.launch"):
+                if path == "greedy":
+                    next_tok, caches, bad = self._decode_greedy(
+                        self.params, {"tokens": jnp.asarray(tokens)},
+                        caches, tables, jnp.asarray(pos), width)
+                else:
+                    next_tok, caches, bad = self._decode_sample(
+                        self.params, {"tokens": jnp.asarray(tokens)},
+                        caches, tables, jnp.asarray(pos), *sampling,
+                        width)
                 self.backend.commit(caches)
-                if bool(np.asarray(bad)):
-                    self._flag_nan()
-                ids = np.asarray(next_tok)
-                return {st.slot: int(ids[st.slot]) for st in active}
-            logits, caches = self._decode(
-                self.params, {"tokens": jnp.asarray(tokens)}, caches,
-                tables, jnp.asarray(pos), width)
-            self.backend.commit(caches)
-            rows = np.asarray(logits.astype(jnp.float32))[:, -1,
-                                                          : self.cfg.vocab]
-            step_end = time.perf_counter()   # np.asarray synced the step
-            if any(np.isnan(rows[st.slot]).any() for st in active):
+            with span("serve.device_wait"):
+                bad = bool(np.asarray(bad))
+            if bad:
                 self._flag_nan()
-                # don't sample from poisoned rows (the host sampler's
-                # softmax would propagate the NaN); step() discards the
-                # step's tokens anyway
-                return {st.slot: 0 for st in active}
-            return {st.slot: sample_token(rows[st.slot],
-                                          st.request.sampling, st.rng)
-                    for st in active}
+            with span("serve.device_wait"):
+                ids = np.asarray(next_tok)
+            return {st.slot: int(ids[st.slot]) for st in active}
         finally:
-            t2 = step_end if step_end is not None else time.perf_counter()
-            self._step_timing[0] += t1 - t0
-            self._step_timing[1] += t2 - t1
-            self._step_timing[2] += 1
             if self._reg is not None:
                 # one series per (path, width) == one compiled decode
                 # variant (width is a static argument of the jit)
@@ -835,6 +827,28 @@ class InferenceServer:
                     labels=("path", "width")).inc(
                     path=path,
                     width="dense" if width is None else str(width))
+
+    def _sampling_inputs(self, active) -> tuple:
+        """The on-device sampler's per-slot operands, and its trace-time
+        flag: rows that truncate need the full-vocab sort; a
+        pure-temperature batch skips it entirely."""
+        temps = np.zeros(self.max_batch, np.float32)
+        topks = np.zeros(self.max_batch, np.int32)
+        seeds = np.zeros(self.max_batch, np.int32)
+        uids = np.zeros(self.max_batch, np.int32)
+        tidx = np.zeros(self.max_batch, np.int32)
+        for st in active:
+            sp = st.request.sampling
+            temps[st.slot] = sp.temperature
+            topks[st.slot] = sp.top_k
+            seeds[st.slot] = sp.seed
+            uids[st.slot] = st.request.uid
+            tidx[st.slot] = len(st.out)
+        need_top_k = batch_need_top_k(
+            [st.request.sampling for st in active], self.cfg.vocab,
+            self._reg)
+        return (jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(seeds),
+                jnp.asarray(uids), jnp.asarray(tidx), need_top_k)
 
     def _append_or_preempt(self, sched, backend, st):
         """Back the request's next cache write with storage; on pool

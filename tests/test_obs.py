@@ -2,9 +2,11 @@
 gauges, histograms, no-op-when-disabled, idempotent phase points), the
 request tracer's lifecycle grammar, end-to-end server tracing across the
 parity matrix (dense/paged x float/quantized x solo/batched/streaming/
-preempted) with scheduler event-ordering properties, and the exporter +
-validator round-trip."""
+preempted) with scheduler event-ordering properties, the exporter +
+validator round-trip, and the serving loop's profiler spans and the
+decode step's named scopes."""
 import json
+import re
 
 import jax
 import numpy as np
@@ -482,3 +484,144 @@ class TestValidateTool:
             {"uid": True, "kind": "decode", "t": 0.5}, schema)
         assert obs_validate.check_schema(
             {"uid": -1, "kind": "decode", "t": 0.5}, schema)
+
+
+# ---------------------------------------------------------------------------
+# profiler spans and named scopes
+# ---------------------------------------------------------------------------
+
+def test_import_obs_does_not_import_jax():
+    import os
+    import pathlib
+    import subprocess
+    import sys
+    src = str(pathlib.Path(__file__).resolve().parents[1] / "src")
+    env = dict(os.environ, PYTHONPATH=src)
+    code = ("import sys, repro.obs; "
+            "assert 'jax' not in sys.modules, 'repro.obs imported jax'; "
+            "assert callable(repro.obs.span)")
+    subprocess.run([sys.executable, "-c", code], env=env, check=True)
+
+
+def test_decode_step_carries_named_scopes(llama):
+    """The greedy paged decode step of a plan-quantized LM: its ops'
+    metadata names the pool handling, the quantized linear, the layers
+    and the sampler, before and after compilation."""
+    cfg, params = llama
+    plan = engine.synthetic_plan(cfg, params, seed=0)
+    server = engine.InferenceServer(cfg, params, plan, cache="paged",
+                                    max_len=32, max_batch=2, page_size=8)
+    be = server.backend
+    lowered = server._decode_greedy.lower(
+        server.params, {"tokens": np.zeros((2, 1), np.int32)}, be.gather(),
+        be.device_tables(), np.zeros((2,), np.int32), be.table_width)
+    text = lowered.as_text(debug_info=True)
+    for path in ("jit(decode_greedy)/kv_pool/", "/attn/kv_pool/",
+                 "/layer0/attn/qlinear/", "/layer0/ffn/qlinear/",
+                 "/layer1/", "jit(decode_greedy)/lm_head/",
+                 "jit(decode_greedy)/sample/"):
+        assert path in text, path
+    compiled = lowered.compile().as_text()
+    for scope in ("kv_pool", "qlinear"):
+        assert re.search(
+            rf'op_name="jit\(decode_greedy\)/\S*{scope}/', compiled), scope
+
+
+class _MarkedNumpy:
+    """``numpy`` as the engine module sees it, with every host readback
+    of a device array (``np.asarray`` of a ``jax.Array``) marked by a
+    ``test.readback`` span in the profiler trace."""
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+    @staticmethod
+    def asarray(x, *args, **kwargs):
+        if isinstance(x, jax.Array):
+            with jax.profiler.TraceAnnotation("test.readback"):
+                return np.asarray(x, *args, **kwargs)
+        return np.asarray(x, *args, **kwargs)
+
+
+def _host_spans(trace_dir):
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    spans = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("serve.", "test.")):
+                    stats = {k: v for k, v in e.stats}
+                    spans.append((e.name, e.start_ns, e.end_ns, stats,
+                                  (plane.name, line.name)))
+    return sorted(spans, key=lambda s: (s[1], -s[2]))
+
+
+def _inside(inner, outer) -> bool:
+    return (inner[4] == outer[4] and outer[1] <= inner[1]
+            and inner[2] <= outer[2])
+
+
+# blocking readbacks of one decode step, by decode path: the NaN flag and
+# the tokens on the device-sampling paths, the logits on the host path
+READBACKS_PER_DECODE = {"greedy": 2, "sample": 2, "host": 1}
+
+
+@pytest.mark.parametrize("path", list(READBACKS_PER_DECODE))
+def test_serve_spans_in_profiler_trace(llama, tmp_path, monkeypatch, path):
+    cfg, params = llama
+    server = engine.InferenceServer(
+        cfg, params, cache="paged", max_len=32, max_batch=2, page_size=8,
+        sample_on_device=path != "host")
+    sp = (SamplingParams(max_tokens=4) if path != "sample" else
+          SamplingParams(temperature=0.8, top_k=5, max_tokens=4, seed=1))
+    # two requests at step 0, a third arriving while they decode
+    server.begin(_reqs(cfg, [5, 9, 7], sp, gap=2))
+    server.step()                        # compile outside the trace
+    monkeypatch.setattr(engine, "np", _MarkedNumpy())
+    jax.profiler.start_trace(str(tmp_path))
+    results = []
+    while server.has_work:
+        results.append(server.step())
+    jax.profiler.stop_trace()
+    monkeypatch.undo()
+    server.end()
+
+    spans = _host_spans(tmp_path)
+    steps = [s for s in spans if s[0] == "serve.step"]
+    assert len(steps) == len(results)
+    for s in spans:
+        if s[0].startswith(("serve.admit", "serve.decode.")):
+            assert any(_inside(s, st) for st in steps), s
+    waits = [s for s in spans if s[0] == "serve.device_wait"]
+    reads = [s for s in spans if s[0] == "test.readback"]
+    # every blocking readback is inside exactly one device_wait span, and
+    # every device_wait span holds exactly one readback
+    assert reads and len(reads) == len(waits)
+    for r in reads:
+        assert sum(_inside(r, w) for w in waits) == 1, r
+    for st, res in zip(steps, results):
+        inner = [s for s in spans if _inside(s, st) and s is not st]
+        names = [s[0] for s in inner]
+        admits = [s for s in inner if s[0] == "serve.admit"]
+        assert len(admits) == 1
+        prefills = [s for s in inner if s[0] == "serve.prefill"]
+        assert ([int(s[3]["uid"]) for s in prefills]
+                == list(res.admitted))
+        assert names.count("serve.sample_first") == len(res.admitted)
+        # the decode's own readbacks: device_wait spans outside admission
+        decode_waits = [s for s in inner if s[0] == "serve.device_wait"
+                        and not any(_inside(s, a) for a in admits)]
+        if res.idle:
+            assert "serve.decode.launch" not in names
+            assert not decode_waits
+        else:
+            for name in ("serve.decode.inputs", "serve.decode.launch",
+                         "serve.bookkeep"):
+                assert names.count(name) == 1, name
+            assert len(decode_waits) == READBACKS_PER_DECODE[path]
+    assert any(r.admitted for r in results)
+    assert any(not r.admitted and not r.idle for r in results)
