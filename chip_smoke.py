@@ -132,11 +132,12 @@ QMM_WHY = "int sums exact in f32, scales round once"
 
 
 def paged_case(rng, lens, hkv, hd, n_pages, width):
-    """A bf16 page pool holding ``lens`` tokens per slot under a random
-    physical layout, as the serve phase's cache holds them."""
-    k = jnp.asarray(rng.normal(size=(n_pages + 1, PAGE_SIZE, hkv, hd)),
+    """A bf16 page pool of lane-dense pages holding ``lens`` tokens per
+    slot under a random physical layout, as the serve phase's cache holds
+    them."""
+    k = jnp.asarray(rng.normal(size=(n_pages + 1, PAGE_SIZE, hkv * hd)),
                     jnp.bfloat16)
-    v = jnp.asarray(rng.normal(size=(n_pages + 1, PAGE_SIZE, hkv, hd)),
+    v = jnp.asarray(rng.normal(size=(n_pages + 1, PAGE_SIZE, hkv * hd)),
                     jnp.bfloat16)
     tables = np.zeros((len(lens), width), np.int32)
     perm = rng.permutation(np.arange(1, n_pages + 1))

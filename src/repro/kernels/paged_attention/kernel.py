@@ -14,7 +14,10 @@ reads the pool **in place**:
     across page blocks.
 
     The K/V block specs index the pool THROUGH the scalar-prefetched
-    block table: ``index_map = (tables[b, p], 0, 0, 0)``.  Entries beyond
+    block table: ``index_map = (tables[b, p], 0, 0)``.  Pages are
+    lane-dense, ``(page_size, Hkv * D)``: a TPU stores such a pool
+    row-major with no padding, so the kernel reads the pool the program
+    holds, with no relayout copy.  Entries beyond
     a slot's live length are 0 (the reserved null page), so consecutive
     dead iterations map to the same physical block and Pallas elides the
     re-fetch; ``pl.when`` skips their compute entirely.  HBM traffic per
@@ -22,7 +25,8 @@ reads the pool **in place**:
     ``max_batch * max_len``.
 
     GQA is handled in-kernel (one 2-D MXU dot per KV head group against
-    the shared K page) -- no head-repeated cache materialization.
+    the shared K page, KV head ``i`` being the static lane slice
+    ``[:, i*D:(i+1)*D]``) -- no head-repeated cache materialization.
 
 Numerics contract: masked positions score ``-1e30`` exactly like the
 dense ``blocks.decode_attention`` path; a slot whose table row is all
@@ -82,16 +86,18 @@ def page_update(q, k, v, m, l, acc, page_start, posn, *, scale: float,
     """One page's online-softmax contribution.  Shared by the kernel body
     and :func:`ref.paged_attention_ref` so the two compute the same math.
 
-    q: (H, D) f32; k/v: (T, Hkv, D) f32; m/l: (H, 1) f32 running
-    max/denominator; acc: (H, D) f32.  Returns updated (m, l, acc).
+    q: (H, D) f32; k/v: (T, Hkv * D) f32 lane-dense pages; m/l: (H, 1)
+    f32 running max/denominator; acc: (H, D) f32.  Returns updated
+    (m, l, acc).
     """
     h, d = q.shape
-    t, hkv, _ = k.shape
+    t = k.shape[0]
+    hkv = k.shape[1] // d
     g = h // hkv
     rows = []
     for i in range(hkv):
         rows.append(jax.lax.dot_general(
-            q[i * g:(i + 1) * g], k[:, i, :],
+            q[i * g:(i + 1) * g], k[:, i * d:(i + 1) * d],
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32))       # (G, T)
     s = jnp.concatenate(rows, axis=0) * scale          # (H, T)
@@ -106,7 +112,7 @@ def page_update(q, k, v, m, l, acc, page_start, posn, *, scale: float,
     outs = []
     for i in range(hkv):
         outs.append(jax.lax.dot_general(
-            p[i * g:(i + 1) * g], v[:, i, :],
+            p[i * g:(i + 1) * g], v[:, i * d:(i + 1) * d],
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32))       # (G, D)
     acc_new = acc * corr + jnp.concatenate(outs, axis=0)
@@ -154,15 +160,16 @@ def paged_attention_fwd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                         window: int = 0, chunked: bool = False,
                         cap: float = 0.0, interpret: bool = True
                         ) -> jax.Array:
-    """q: (B, H, D); k_pool/v_pool: (n_pages + 1, page_size, Hkv, D) with
-    physical page 0 the reserved null page; tables: (B, P) int32 physical
-    page ids (0 = unbacked); pos: (B,) int32 per-slot decode positions.
+    """q: (B, H, D); k_pool/v_pool: (n, page_size, Hkv * D) lane-dense
+    pages with page 0 the reserved null page; tables: (B, P) int32 page
+    ids (0 = unbacked); pos: (B,) int32 per-slot decode positions.
     Returns (B, H, D) in q's dtype.
     """
     b, h, d = q.shape
-    page_size, hkv = k_pool.shape[1], k_pool.shape[2]
+    page_size, width = k_pool.shape[1], k_pool.shape[2]
+    hkv = width // d
     n_pb = tables.shape[1]
-    assert h % hkv == 0, (h, hkv)
+    assert width == hkv * d and h % hkv == 0, (h, width, d)
     scale = 1.0 / math.sqrt(d)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -170,10 +177,10 @@ def paged_attention_fwd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         grid=(b, n_pb),
         in_specs=[
             pl.BlockSpec((1, h, d), lambda bb, p, tbl, ps: (bb, 0, 0)),
-            pl.BlockSpec((1, page_size, hkv, d),
-                         lambda bb, p, tbl, ps: (tbl[bb, p], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, hkv, d),
-                         lambda bb, p, tbl, ps: (tbl[bb, p], 0, 0, 0)),
+            pl.BlockSpec((1, page_size, width),
+                         lambda bb, p, tbl, ps: (tbl[bb, p], 0, 0)),
+            pl.BlockSpec((1, page_size, width),
+                         lambda bb, p, tbl, ps: (tbl[bb, p], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, h, d), lambda bb, p, tbl, ps: (bb, 0, 0)),
         scratch_shapes=[

@@ -71,8 +71,8 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     chunked: bool = False, cap: float = 0.0,
                     impl: str | None = None) -> jax.Array:
     """Decode attention over the page pool.  q: (B, H, D);
-    k_pool/v_pool: (n_pages + 1, page_size, Hkv, D); tables: (B, P)
-    physical page ids (0 = null); pos: (B,) per-slot positions.
+    k_pool/v_pool: (n, page_size, Hkv * D) lane-dense pages; tables:
+    (B, P) page ids (0 = null); pos: (B,) per-slot positions.
     Returns (B, H, D) in q's dtype."""
     impl = resolve_impl(impl)
     if impl == "ref":
@@ -103,8 +103,8 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
                             impl: str | None = None) -> jax.Array:
     """Prefill attention over the page pool.  q: (B, S, H, D) -- the
     prompt's queries, rows at or beyond ``lens`` being discarded
-    padding; k_pool/v_pool: (n_pages + 1, page_size, Hkv, D); tables:
-    (B, P) physical page ids (0 = null); lens: (B,) real prompt
+    padding; k_pool/v_pool: (n, page_size, Hkv * D) lane-dense pages;
+    tables: (B, P) page ids (0 = null); lens: (B,) real prompt
     lengths.  Returns (B, S, H, D) in q's dtype."""
     impl = resolve_impl(impl)
     if impl == "ref":

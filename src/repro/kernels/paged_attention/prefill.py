@@ -17,15 +17,17 @@ place**:
     across page blocks.
 
     The K/V block specs index the pool THROUGH the scalar-prefetched
-    block table, exactly like decode: ``index_map = (tables[b, p], 0, 0,
-    0)``.  Null (physical page 0) entries collapse consecutive dead
+    block table, exactly like decode: ``index_map = (tables[b, p], 0,
+    0)`` over lane-dense ``(page_size, Hkv * D)`` pages.  Null (page 0)
+    entries collapse consecutive dead
     iterations onto one block -- Pallas elides the re-fetch -- and
     ``pl.when`` skips their compute entirely, including every page that
     lies wholly above the q chunk (causal) or wholly below the attention
     window.
 
     GQA is in-kernel: one (Q*G, T) MXU dot per KV head group against the
-    shared K page -- no head-repeated materialization.
+    shared K page, KV head ``i`` being the static lane slice
+    ``[:, i*D:(i+1)*D]`` -- no head-repeated materialization.
 
 Numerics contract: identical to decode -- masked positions score
 ``-1e30`` and :func:`paged_prefill_ref` mirrors the kernel
@@ -96,17 +98,19 @@ def prefill_page_update(q, k, v, m, l, acc, page_start, qc_start, *,
     the kernel body and :func:`paged_prefill_ref` so the two compute the
     same math.
 
-    q: (Q, H, D) f32; k/v: (T, Hkv, D) f32; m/l: (Q, H, 1) f32 running
-    max/denominator; acc: (Q, H, D) f32.  Returns updated (m, l, acc).
+    q: (Q, H, D) f32; k/v: (T, Hkv * D) f32 lane-dense pages; m/l:
+    (Q, H, 1) f32 running max/denominator; acc: (Q, H, D) f32.  Returns
+    updated (m, l, acc).
     """
     qc, h, d = q.shape
-    t, hkv, _ = k.shape
+    t = k.shape[0]
+    hkv = k.shape[1] // d
     g = h // hkv
     rows = []
     for i in range(hkv):
         qg = q[:, i * g:(i + 1) * g, :].reshape(qc * g, d)
         rows.append(jax.lax.dot_general(
-            qg, k[:, i, :], (((1,), (1,)), ((), ())),
+            qg, k[:, i * d:(i + 1) * d], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32
         ).reshape(qc, g, t))                               # (Q, G, T)
     s = jnp.concatenate(rows, axis=1) * scale              # (Q, H, T)
@@ -123,7 +127,7 @@ def prefill_page_update(q, k, v, m, l, acc, page_start, qc_start, *,
     for i in range(hkv):
         pg = p[:, i * g:(i + 1) * g, :].reshape(qc * g, t)
         outs.append(jax.lax.dot_general(
-            pg, v[:, i, :], (((1,), (0,)), ((), ())),
+            pg, v[:, i * d:(i + 1) * d], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32
         ).reshape(qc, g, d))                               # (Q, G, D)
     acc_new = acc * corr + jnp.concatenate(outs, axis=1)
@@ -177,17 +181,18 @@ def paged_prefill_fwd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                       cap: float = 0.0, q_chunk: int = 16,
                       interpret: bool = True) -> jax.Array:
     """q: (B, S, H, D) with S a multiple of ``q_chunk`` (the caller pads;
-    padded rows produce discarded garbage); k_pool/v_pool: (n_pages + 1,
-    page_size, Hkv, D) with physical page 0 the reserved null page;
-    tables: (B, P) int32 physical page ids (0 = unbacked); lens: (B,)
-    int32 real prompt lengths.  Returns (B, S, H, D) in q's dtype.
+    padded rows produce discarded garbage); k_pool/v_pool: (n,
+    page_size, Hkv * D) lane-dense pages with page 0 the reserved null
+    page; tables: (B, P) int32 page ids (0 = unbacked); lens: (B,) int32
+    real prompt lengths.  Returns (B, S, H, D) in q's dtype.
     """
     b, s, h, d = q.shape
-    page_size, hkv = k_pool.shape[1], k_pool.shape[2]
+    page_size, width = k_pool.shape[1], k_pool.shape[2]
+    hkv = width // d
     n_pb = tables.shape[1]
     q_chunk = min(q_chunk, s)
     assert s % q_chunk == 0, (s, q_chunk)
-    assert h % hkv == 0, (h, hkv)
+    assert width == hkv * d and h % hkv == 0, (h, width, d)
     n_qc = s // q_chunk
     scale = 1.0 / math.sqrt(d)
 
@@ -197,10 +202,10 @@ def paged_prefill_fwd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         in_specs=[
             pl.BlockSpec((1, q_chunk, h, d),
                          lambda bb, qc, p, tbl, ln: (bb, qc, 0, 0)),
-            pl.BlockSpec((1, page_size, hkv, d),
-                         lambda bb, qc, p, tbl, ln: (tbl[bb, p], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, hkv, d),
-                         lambda bb, qc, p, tbl, ln: (tbl[bb, p], 0, 0, 0)),
+            pl.BlockSpec((1, page_size, width),
+                         lambda bb, qc, p, tbl, ln: (tbl[bb, p], 0, 0)),
+            pl.BlockSpec((1, page_size, width),
+                         lambda bb, qc, p, tbl, ln: (tbl[bb, p], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, q_chunk, h, d),
                                lambda bb, qc, p, tbl, ln: (bb, qc, 0, 0)),
@@ -297,7 +302,7 @@ def paged_prefill_view(q: jax.Array, k_pool: jax.Array,
     the inline replica.
     """
     b, s, h, d = q.shape
-    hkv = k_pool.shape[2]
+    hkv = k_pool.shape[2] // d
     k = k_pool[tables].reshape(b, -1, hkv, d)
     v = v_pool[tables].reshape(b, -1, hkv, d)
     del lens  # real rows self-select via the causal mask

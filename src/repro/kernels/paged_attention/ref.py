@@ -34,8 +34,8 @@ def paged_attention_ref(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                         cap: float = 0.0) -> jax.Array:
     """Tolerance oracle of the Pallas kernel (see module docstring).
 
-    q: (B, H, D); k_pool/v_pool: (n_pages + 1, page_size, Hkv, D);
-    tables: (B, P); pos: (B,).  Returns (B, H, D) in q's dtype.
+    q: (B, H, D); k_pool/v_pool: (n, page_size, Hkv * D) lane-dense
+    pages; tables: (B, P); pos: (B,).  Returns (B, H, D) in q's dtype.
     """
     b, h, d = q.shape
     page_size = k_pool.shape[1]
@@ -82,7 +82,7 @@ def paged_attention_view(q: jax.Array, k_pool: jax.Array,
     bitwise identical to the dense cache backend.
     """
     b, h, d = q.shape
-    hkv = k_pool.shape[2]
+    hkv = k_pool.shape[2] // d
     ck = k_pool[tables].reshape(b, -1, hkv, d)
     cv = v_pool[tables].reshape(b, -1, hkv, d)
     s = ck.shape[1]

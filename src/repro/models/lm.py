@@ -338,13 +338,80 @@ def _layer_apply(cfg, spec: LayerSpec, p, x, *, mode, cache, pos,
     return x, (new_cache or None)
 
 
+def _split_pools(caches, tables):
+    """Paged serving: take the KV pools out of a stacked cache tree.
+
+    Returns ``(pools, rest, n1)``.  ``pools`` maps each attention slot of
+    the pattern to its ``{"k", "v"}`` pools, each ``(nsb, n1, page_size,
+    hkv * hd)`` viewed as one flat ``(nsb * n1, page_size, hkv * hd)``
+    array -- the reshape merges leading axes only, so it is a bitcast --
+    in which super-block ``j`` owns pages ``[j * n1, (j + 1) * n1)`` (see
+    :func:`layer_tables`).  ``rest`` is the remainder of the tree (SSM
+    state), still stacked ``(nsb, ...)``.  Dense caches (``tables`` None)
+    have no pools: everything is ``rest``."""
+    if caches is None or tables is None:
+        return {}, caches, 0
+    pools, rest, n1 = {}, {}, 0
+    for name, c in caches.items():
+        if "kv" in c:
+            n1 = c["kv"]["k"].shape[1]
+            pools[name] = jax.tree.map(
+                lambda a: a.reshape(-1, *a.shape[2:]), c["kv"])
+        other = {k: v for k, v in c.items() if k != "kv"}
+        if other:
+            rest[name] = other
+    return pools, (rest or None), n1
+
+
+def _join_pools(pools, rest, n1):
+    """Inverse of :func:`_split_pools` on a runner's outputs: the flat
+    pools reshaped back to ``(nsb, n1, ...)`` beside the stacked rest."""
+    out = {} if rest is None else {n: dict(c) for n, c in rest.items()}
+    for name, kv in pools.items():
+        out.setdefault(name, {})["kv"] = jax.tree.map(
+            lambda a: a.reshape(-1, n1, *a.shape[1:]), kv)
+    return out or None
+
+
+def layer_tables(tables, j, n1: int):
+    """Block tables of super-block ``j`` in the flat pool of
+    :func:`_split_pools`: each live page id offset by ``j * n1``.  Null
+    entries stay 0, so the null page of every layer is layer 0's page 0
+    and the kernels' ``phys != 0`` liveness test is unchanged."""
+    return jnp.where(tables != 0, tables + j * n1, 0)
+
+
+def _layer_cache(blk_rest, pools, name):
+    """One pattern slot's cache: its slice of the rest, plus its flat KV
+    pools."""
+    c = dict((blk_rest or {}).get(name) or {})
+    if name in pools:
+        c["kv"] = pools[name]
+    return c or None
+
+
+def _take_pools(nc, pools, name):
+    """Thread a layer's updated flat pools into ``pools``; return what
+    is left of its new cache (SSM state, or a dense cache) or None."""
+    if nc is None:
+        return None
+    nc = dict(nc)
+    if name in pools:
+        pools[name] = nc.pop("kv")
+    return nc or None
+
+
 def _run_stack(cfg, pattern, stack_params, x, *, mode, caches, pos,
                enc_out, getw, remat: bool, blk_logical=None, tables=None):
     """scan over super-blocks. caches: pytree stacked on axis 0 or None.
 
-    tables: paged-decode block tables (B, P), shared by every layer (one
-    physical page id backs a token position across ALL layers, so the
-    table is scan-invariant and closed over, not scanned).
+    tables: paged block tables (B, P) of page ids, shared by every layer
+    (one page id backs a token position across ALL layers).  The KV pools
+    are not scanned: they ride in the scan carry as flat arrays
+    (:func:`_split_pools`), the layer index comes from an ``arange`` in
+    ``xs``, and each super-block reads and writes its own pages in place
+    through :func:`layer_tables`.  Only SSM state (small, per slot) is
+    sliced and stacked by the scan.
 
     blk_logical: logical-axis tree matching one *sliced* block (leading
     'layers' axis stripped). Constraining the sliced weights inside the
@@ -354,34 +421,41 @@ def _run_stack(cfg, pattern, stack_params, x, *, mode, caches, pos,
     and materializes every layer's gathered weights at once (165 GiB/dev
     for jamba-398B; see EXPERIMENTS.md Sec-Perf iteration 0).
     """
-    _is_axes = lambda v: isinstance(v, tuple)  # noqa: E731
+    pools, rest, n1 = _split_pools(caches, tables)
+    nsb = jax.tree.leaves(stack_params)[0].shape[0]
 
     def block_fn(carry, xs):
-        xv = carry
+        xv, pools = carry
+        pools = dict(pools)
         in_dtype = xv.dtype
-        blk_params, blk_cache = xs
+        blk_params, blk_rest, j = xs
         if blk_logical is not None and sharding.get_mesh() is not None:
             blk_params = jax.tree.map(
                 lambda p, l: sharding.constrain(p, *l),
                 blk_params, blk_logical)
         xv = sharding.constrain(xv, "batch", "act_seq", "embed")
-        new_caches = {}
+        with jax.named_scope("kv_pool"):
+            tables_j = layer_tables(tables, j, n1) if pools else tables
+        new_rest = {}
         for i, spec in enumerate(pattern):
-            cache_i = None if blk_cache is None else blk_cache.get(f"l{i}")
-            xv, nc = _layer_apply(cfg, spec, blk_params[f"l{i}"], xv,
-                                  mode=mode, cache=cache_i, pos=pos,
-                                  enc_out=enc_out, getw=getw,
-                                  tables=tables)
+            name = f"l{i}"
+            xv, nc = _layer_apply(cfg, spec, blk_params[name], xv,
+                                  mode=mode,
+                                  cache=_layer_cache(blk_rest, pools, name),
+                                  pos=pos, enc_out=enc_out, getw=getw,
+                                  tables=tables_j)
+            nc = _take_pools(nc, pools, name)
             if nc is not None:
-                new_caches[f"l{i}"] = nc
-        return xv.astype(in_dtype), (new_caches or None)
+                new_rest[name] = nc
+        return (xv.astype(in_dtype), pools), (new_rest or None)
 
     fn = block_fn
     if remat:
         fn = jax.checkpoint(block_fn,
                             policy=jax.checkpoint_policies.nothing_saveable)
-    x, new_caches = jax.lax.scan(fn, x, (stack_params, caches))
-    return x, new_caches
+    (x, pools), new_rest = jax.lax.scan(
+        fn, (x, pools), (stack_params, rest, jnp.arange(nsb)))
+    return x, _join_pools(pools, new_rest, n1)
 
 
 def _run_stack_unrolled(cfg, pattern, per_sb_params, x, *, mode, caches,
@@ -392,32 +466,39 @@ def _run_stack_unrolled(cfg, pattern, per_sb_params, x, *, mode, caches,
     :class:`~repro.nn.quantized.PackedLinear` buffers have layer-dependent
     shapes (different per-precision channel counts), so they cannot be
     stacked for a ``lax.scan``.  Caches keep the stacked ``(nsb, ...)``
-    layout of :func:`init_caches`: each block's cache is sliced out of
-    the stack and the stack is rebuilt after the last block, both under
-    the ``kv_pool`` scope; each layer runs under ``layer{n}``."""
-    per_sb_caches = []
+    layout of :func:`init_caches` / :func:`init_paged_caches`.  KV pools
+    are never sliced: one flat pool threads from layer to layer and
+    super-block ``j`` addresses its pages through :func:`layer_tables`
+    (under the ``kv_pool`` scope).  Dense caches and SSM state are sliced
+    per block and stacked again after the last; each layer runs under
+    ``layer{n}``."""
+    pools, rest, n1 = _split_pools(caches, tables)
+    per_sb_rest = []
     for j, blk_params in enumerate(per_sb_params):
         with jax.named_scope("kv_pool"):
-            blk_cache = None if caches is None else \
-                jax.tree.map(lambda a: a[j], caches)
-        new_caches = {}
+            blk_rest = None if rest is None else \
+                jax.tree.map(lambda a: a[j], rest)
+            tables_j = layer_tables(tables, j, n1) if pools else tables
+        new_rest = {}
         for i, spec in enumerate(pattern):
-            cache_i = None if blk_cache is None else blk_cache.get(f"l{i}")
+            name = f"l{i}"
             with jax.named_scope(f"layer{j * len(pattern) + i}"):
-                x, nc = _layer_apply(cfg, spec, blk_params[f"l{i}"], x,
-                                     mode=mode, cache=cache_i, pos=pos,
-                                     enc_out=enc_out, getw=getw,
-                                     tables=tables)
+                x, nc = _layer_apply(cfg, spec, blk_params[name], x,
+                                     mode=mode,
+                                     cache=_layer_cache(blk_rest, pools,
+                                                        name),
+                                     pos=pos, enc_out=enc_out, getw=getw,
+                                     tables=tables_j)
+            nc = _take_pools(nc, pools, name)
             if nc is not None:
-                new_caches[f"l{i}"] = nc
-        per_sb_caches.append(new_caches or None)
-    if any(c is not None for c in per_sb_caches):
+                new_rest[name] = nc
+        per_sb_rest.append(new_rest or None)
+    stacked = None
+    if any(c is not None for c in per_sb_rest):
         with jax.named_scope("kv_pool"):
             stacked = jax.tree.map(lambda *xs: jnp.stack(xs, axis=0),
-                                   *per_sb_caches)
-    else:
-        stacked = None
-    return x, stacked
+                                   *per_sb_rest)
+    return x, _join_pools(pools, stacked, n1)
 
 
 def _has_gamma(tree) -> bool:
@@ -681,13 +762,19 @@ def init_paged_caches(cfg: ArchConfig, batch: int, page_size: int,
     serving is decoder-only).
 
     KV tensors become fixed page pools ``(nsb, n_pages + 1, page_size,
-    hkv, hd)`` indexed by physical page id -- page 0 is the reserved null
+    hkv * hd)`` indexed by physical page id -- page 0 is the reserved null
     page that inactive block-table entries point at (written garbage is
-    always masked).  SSM state is O(1) per request, so it keeps the dense
-    per-slot layout ``(nsb, batch, ...)``.  The per-request block tables
-    are NOT part of this tree; the cache backend composes them in at
-    gather time (they are host-side bookkeeping that changes on admission
-    / page allocation, not per decode step).
+    always masked).  A page is lane-dense: each token's KV heads lie side
+    by side in one ``hkv * hd`` row, so a TPU stores the pool row-major
+    with no padding, which is the layout the paged kernels read.  Inside
+    a step the stack runners view each pool as one flat ``(nsb * (n_pages
+    + 1), ...)`` array and address super-block ``j`` by page ids offset
+    by ``j * (n_pages + 1)`` (:func:`layer_tables`), so no layer's pool is
+    ever sliced out or stacked back.  SSM state is O(1) per request, so
+    it keeps the dense per-slot layout ``(nsb, batch, ...)``.  The
+    per-request block tables are NOT part of this tree; the cache backend
+    composes them in at gather time (they are host-side bookkeeping that
+    changes on admission / page allocation, not per decode step).
     """
     if cfg.is_encdec:
         raise NotImplementedError("paged caches are decoder-only")
@@ -712,8 +799,8 @@ def init_paged_caches(cfg: ArchConfig, batch: int, page_size: int,
                     "c": mk((nsb, batch, cfg.ssm_conv - 1, cfg.ssm_state)),
                 }}
         else:
-            c["kv"] = {"k": mk((nsb, n_pages + 1, page_size, hkv, hd)),
-                       "v": mk((nsb, n_pages + 1, page_size, hkv, hd))}
+            c["kv"] = {"k": mk((nsb, n_pages + 1, page_size, hkv * hd)),
+                       "v": mk((nsb, n_pages + 1, page_size, hkv * hd))}
         caches[f"l{i}"] = c
     return caches
 
@@ -723,10 +810,11 @@ def _tree_bytes(tree) -> int:
     return int(sum(l.size * jnp.dtype(l.dtype).itemsize for l in leaves))
 
 
-def kv_bytes_per_token(cfg: ArchConfig) -> int:
-    """Bytes of KV cache one token position pins across all attention
-    layers (0 for pure-SSM architectures)."""
-    tree = init_caches(cfg, 1, 1, abstract=True)
+def kv_bytes_per_page(cfg: ArchConfig, page_size: int) -> int:
+    """Bytes one page id pins across all attention layers' pools of
+    :func:`init_paged_caches` (0 for pure-SSM architectures): the pools
+    of a one-page tree (the null page alone)."""
+    tree = init_paged_caches(cfg, 1, page_size, 0, abstract=True)
     return _tree_bytes({l: {"kv": c["kv"]} for l, c in tree.items()
                         if "kv" in c})
 

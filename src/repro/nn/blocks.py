@@ -208,8 +208,8 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            chunked: bool = False, cap: float = 0.0
                            ) -> jax.Array:
     """One-token attention straight over the KV page pool (no dense
-    gather).  q: (B, 1, H, D); k_pool/v_pool: (n_pages + 1, page_size,
-    Hkv, D); tables: (B, P) physical page ids (0 = reserved null page);
+    gather).  q: (B, 1, H, D); k_pool/v_pool: (n, page_size, Hkv * D)
+    lane-dense pages; tables: (B, P) page ids (0 = reserved null page);
     pos: (B,) per-slot positions.  Dispatches to the Pallas kernel on
     TPU and to the gathered-view reference (bitwise identical to
     :func:`decode_attention` over the dense row) off-TPU."""
@@ -226,9 +226,9 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
                             ) -> jax.Array:
     """Prompt attention straight over the KV page pool (no dense
     round-trip).  q: (B, S, H, D) with rows at or beyond ``lens``
-    being discarded padding; k_pool/v_pool: (n_pages + 1, page_size,
-    Hkv, D); tables: (B, P) physical page ids (0 = reserved null
-    page); lens: (B,) real prompt lengths.  Dispatches to the
+    being discarded padding; k_pool/v_pool: (n, page_size, Hkv * D)
+    lane-dense pages; tables: (B, P) page ids (0 = reserved null page);
+    lens: (B,) real prompt lengths.  Dispatches to the
     q-chunked Pallas kernel on TPU and to the gathered-view reference
     (the dense :func:`flash_attention` op sequence) off-TPU."""
     return paged_ops.paged_prefill_attention(q, k_pool, v_pool, tables,
@@ -256,7 +256,8 @@ def attention_layer(p: dict, x: jax.Array, cfg, *, kind: str = "full",
 
     tables (decode + prefill): (B, P) int32 per-slot block tables of a
     :class:`~repro.serve.cache.PagedCache` -- cache["k"/"v"] are then
-    page POOLS of shape (n_pages + 1, page_size, Hkv, D) and attention
+    page POOLS of shape (n, page_size, Hkv * D) (every layer's pages in
+    one flat pool, `tables` already offset to this layer's) and attention
     runs directly on the pool (:func:`paged_decode_attention` /
     :func:`paged_prefill_attention`); for mode="prefill", `pos` carries
     the (B,) real prompt lengths.  The tables ride OUTSIDE the
@@ -301,8 +302,8 @@ def attention_layer(p: dict, x: jax.Array, cfg, *, kind: str = "full",
         kk = rope(kk, pos_rope, cfg.rope_theta)
         if cache is not None and tables is not None:
             # paged KV (serve.cache.PagedCache): cache["k"/"v"] are page
-            # pools (n_pages + 1, page_size, hkv, hd), `tables` the
-            # per-slot block tables (B, P) of physical page ids.  The
+            # pools (n, page_size, hkv * hd), `tables` the per-slot
+            # block tables (B, P) of this layer's page ids.  The
             # step's only cache write is the token's (B,) K/V rows
             # scattered at (tables[b, pos//ps], pos%ps) -- with the tree
             # donated this is an in-place page write -- and attention
@@ -316,10 +317,10 @@ def attention_layer(p: dict, x: jax.Array, cfg, *, kind: str = "full",
             with jax.named_scope("kv_pool"):
                 phys = tables[rows, pos_b // page_size]      # (B,)
                 off = pos_b % page_size
-                ck = cache["k"].at[phys, off].set(kk[:, 0].astype(
-                    cache["k"].dtype))
-                cv = cache["v"].at[phys, off].set(vv[:, 0].astype(
-                    cache["v"].dtype))
+                ck = cache["k"].at[phys, off].set(
+                    kk[:, 0].reshape(b, hkv * hd).astype(cache["k"].dtype))
+                cv = cache["v"].at[phys, off].set(
+                    vv[:, 0].reshape(b, hkv * hd).astype(cache["v"].dtype))
             new_cache = {"k": ck, "v": cv}
             out = paged_decode_attention(q, ck, cv, tables, pos_b,
                                          window=window, chunked=chunked,
@@ -368,9 +369,11 @@ def attention_layer(p: dict, x: jax.Array, cfg, *, kind: str = "full",
                 off = jnp.broadcast_to(positions[None, :] % page_size,
                                        (b, s))
                 ck = cache["k"].at[phys, off].set(
-                    kk.astype(cache["k"].dtype), mode="drop")
+                    kk.reshape(b, s, hkv * hd).astype(cache["k"].dtype),
+                    mode="drop")
                 cv = cache["v"].at[phys, off].set(
-                    vv.astype(cache["v"].dtype), mode="drop")
+                    vv.reshape(b, s, hkv * hd).astype(cache["v"].dtype),
+                    mode="drop")
             new_cache = {"k": ck, "v": cv}
             out = paged_prefill_attention(q, ck, cv, tables, lens_b,
                                           window=window, chunked=chunked,

@@ -32,7 +32,13 @@ Two implementations:
   per request and lives in a parallel per-slot pool.  Physical page 0 is
   a reserved null page: inactive slots and unused block-table entries
   point at it, and anything written there is only ever read at masked
-  positions.
+  positions.  Each KV pool is ``(nsb, n_pages + 1, page_size, hkv *
+  hd)``: lane-dense pages (the layout a TPU holds without padding and
+  the paged kernels read) for every layer at once.  A step addresses
+  layer ``j``'s page ``t`` as page ``t + j * (n_pages + 1)`` of the
+  flat pool (``lm.layer_tables``), so one page id backs a token in
+  every layer, the tables here stay per slot, and no layer's pool is
+  ever sliced out or restacked.
 
 The backends' contract is *token-for-token invariance*: the same request
 stream produces identical tokens on either backend (and solo vs.
@@ -315,8 +321,7 @@ class PagedCache(CacheBackend):
         self._handles: dict[int, CacheHandle] = {}
         self._peak_pages = 0
 
-        kv_tok = lm.kv_bytes_per_token(cfg)
-        self.bytes_per_page = kv_tok * self.page_size
+        self.bytes_per_page = lm.kv_bytes_per_page(cfg, self.page_size)
         self.ssm_slot_bytes = lm.ssm_bytes_per_slot(cfg)
         self.dense_equivalent_bytes = lm.dense_cache_bytes(
             cfg, max_batch, max_len)

@@ -8,6 +8,8 @@ matrix (solo/batched/streaming/preempted re-prefill under every prefill
 impl, page-boundary prompt footprints, one bounded table upload per
 admission), the device-resident block tables (no per-step host sync),
 and the on-device sampling path vs. the host fallback."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -320,6 +322,99 @@ class TestPagedDenseParity:
         out = paged.serve(_reqs(cfg, (33, 17), sp, seed=2))
         for i in range(2):
             np.testing.assert_array_equal(ref[i], out[i])
+
+
+class TestLayerAddressedPool:
+    """Each KV pool holds every layer's pages, ``(nsb, n_pages + 1,
+    page_size, hkv * hd)``, and the stack runners address layer ``j``'s
+    pages in place.  After a decode or prefill step each layer's pages,
+    read back through the tables, hold exactly the dense cache of that
+    layer, and no page that no live slot owns has changed in any layer:
+    writing layer ``j`` never changes layer ``k``'s pages."""
+
+    PS, N_PB, N_PAGES = 4, 4, 12
+
+    def _case(self, cfg, b, seed):
+        rng = np.random.default_rng(seed)
+        caches = lm.init_paged_caches(cfg, b, self.PS, self.N_PAGES)
+        caches = jax.tree.map(
+            lambda a: jnp.asarray(rng.normal(size=a.shape), a.dtype),
+            caches)
+        tables = np.zeros((b, self.N_PB), np.int32)
+        tables.flat[:] = rng.permutation(
+            np.arange(1, self.N_PAGES + 1))[:tables.size]
+        return caches, tables
+
+    @staticmethod
+    def _rows(pool, tables, cfg):
+        """(nsb, B, P * page_size, hkv, hd) rows of every layer."""
+        pool = np.asarray(pool)
+        return pool[:, tables].reshape(pool.shape[0], tables.shape[0], -1,
+                                       cfg.hkv_eff, cfg.head_dim)
+
+    def _check_pages(self, cfg, old, new, want, tables, live):
+        owned = np.zeros(self.N_PAGES + 1, bool)
+        owned[tables[list(live)]] = True
+        owned[0] = True                     # the null page: garbage
+        for k in ("k", "v"):
+            got = np.asarray(new["l0"]["kv"][k])
+            assert got.shape == np.asarray(old["l0"]["kv"][k]).shape
+            np.testing.assert_array_equal(
+                got[:, ~owned], np.asarray(old["l0"]["kv"][k])[:, ~owned])
+            rows = self._rows(got, tables, cfg)
+            for b, n in live.items():
+                np.testing.assert_array_equal(
+                    rows[:, b, :n], np.asarray(want["l0"]["kv"][k])[:, b, :n])
+
+    @staticmethod
+    def _params(params, runner):
+        if runner == "scanned":
+            return params
+        nsb = jax.tree.leaves(params["blocks"])[0].shape[0]
+        return dict(params, blocks=tuple(
+            jax.tree.map(lambda a, j=j: a[j], params["blocks"])
+            for j in range(nsb)))
+
+    @pytest.mark.parametrize("runner", ["scanned", "unrolled"])
+    def test_decode_writes_stay_in_own_layer_pages(self, llama, runner):
+        cfg, params = llama
+        params = self._params(params, runner)
+        assert lm.n_superblocks(cfg) >= 2
+        caches, tables = self._case(cfg, 3, seed=21)
+        tables[1] = 0                                  # an inactive slot
+        pos = np.asarray([6, 0, 13], np.int32)
+        live = {0: 7, 2: 14}                           # slot: tokens held
+        dense = {"l0": {"kv": {k: jnp.asarray(self._rows(
+            caches["l0"]["kv"][k], tables, cfg))
+            for k in ("k", "v")}}}
+        tok = {"tokens": jnp.asarray([[5], [9], [17]], jnp.int32)}
+        lg_d, want = jax.jit(functools.partial(lm.decode_step, cfg))(
+            params, tok, dense, jnp.asarray(pos))
+        lg_p, got = jax.jit(functools.partial(lm.decode_step, cfg))(
+            params, tok, caches, jnp.asarray(pos),
+            tables=jnp.asarray(tables))
+        np.testing.assert_array_equal(np.asarray(lg_p)[[0, 2]],
+                                      np.asarray(lg_d)[[0, 2]])
+        self._check_pages(cfg, caches, got, want, tables, live)
+
+    @pytest.mark.parametrize("runner", ["scanned", "unrolled"])
+    def test_prefill_writes_stay_in_own_layer_pages(self, llama, runner):
+        cfg, params = llama
+        params = self._params(params, runner)
+        caches, tables = self._case(cfg, 1, seed=22)
+        n, s = 13, self.PS * self.N_PB
+        toks = np.zeros((1, s), np.int32)
+        toks[0, :n] = _prompts(cfg, (n,), seed=4)[0]
+        lg_d, want = jax.jit(functools.partial(
+            lm.forward, cfg, mode="prefill", logits_mode="last",
+            last_pos=n - 1))(params, {"tokens": jnp.asarray(toks)})
+        lg_p, got = jax.jit(functools.partial(
+            lm.forward, cfg, mode="prefill", logits_mode="last",
+            last_pos=n - 1))(params, {"tokens": jnp.asarray(toks)},
+                             caches=caches, pos=jnp.asarray([n]),
+                             tables=jnp.asarray(tables))
+        np.testing.assert_array_equal(np.asarray(lg_p), np.asarray(lg_d))
+        self._check_pages(cfg, caches, got, want, tables, {0: n})
 
 
 class TestPagedKernelParity:

@@ -11,8 +11,13 @@ The contract under test (see src/repro/kernels/README.md):
     NaN-poisoned null page cannot reach the output;
   * the result depends only on the LOGICAL cache content -- physical
     page permutations, garbage in partial last pages, and freed
-    mid-batch slots do not change live slots' outputs.
+    mid-batch slots do not change live slots' outputs;
+  * every layer's pages live in one flat pool, addressed by page ids
+    offset by layer: attention and page writes through it equal those
+    of separate per-layer pools, and a layer's writes never reach
+    another layer's pages.
 """
+import dataclasses
 import functools
 
 import jax
@@ -26,6 +31,9 @@ from repro.kernels.paged_attention import ref as pref
 from repro.nn import blocks
 
 import proptest as pt
+from paged_pools import random_pools
+from repro.configs import registry
+from repro.models import lm
 
 # kernel vs its ref: the same f32 online softmax, but whether each page's
 # rescale-then-add (`l * corr + sum`, `acc * corr + pv`) is contracted
@@ -46,8 +54,7 @@ def make_case(rng, lens, *, h=4, hkv=2, hd=16, ps=8, n_pb=4,
     b = len(lens)
     if n_pages is None:
         n_pages = b * n_pb
-    pool_k = rng.normal(size=(n_pages + 1, ps, hkv, hd)).astype(np.float32)
-    pool_v = rng.normal(size=(n_pages + 1, ps, hkv, hd)).astype(np.float32)
+    pool_k, pool_v = random_pools(rng, n_pages, ps, hkv, hd)
     if poison_null:
         pool_k[0] = np.nan
         pool_v[0] = np.nan
@@ -240,3 +247,80 @@ class TestDispatch:
                                    **KERNEL_REF_TOL)
         np.testing.assert_allclose(outs["kernel"], outs["view"],
                                    rtol=2e-5, atol=2e-5)
+
+
+class TestFlatLayerPool:
+    """The stack runners hold every layer's pages in one flat pool and
+    address layer ``j`` through ``tables + j * n1``: per layer, attention
+    and its page writes equal those of a pool of its own."""
+
+    LAYERS, N_PAGES, PS, N_PB = 3, 8, 4, 4
+
+    @pytest.mark.parametrize("impl", ["kernel", "view"])
+    @pytest.mark.parametrize("window", [0, 6])
+    @pytest.mark.parametrize("mode", ["decode", "prefill"])
+    def test_flat_pool_matches_per_layer_pools(self, mode, window, impl):
+        cfg = dataclasses.replace(registry.get("llama3.2-1b-smoke"),
+                                  local_window=window)
+        hkv, hd, d = cfg.hkv_eff, cfg.head_dim, cfg.d_model
+        rng = np.random.default_rng(11)
+        n1 = self.N_PAGES + 1
+        lens = np.asarray([5, 0, 11], np.int32)       # slot 1: null row
+        b = len(lens)
+        tables = np.zeros((b, self.N_PB), np.int32)
+        perm = rng.permutation(np.arange(1, n1))
+        idx = 0
+        for bi, n in enumerate(lens):
+            for pg in range(-(-(int(n) + 1) // self.PS) if n else 0):
+                tables[bi, pg] = perm[idx]
+                idx += 1
+        pools = [random_pools(rng, self.N_PAGES, self.PS, hkv, hd)
+                 for _ in range(self.LAYERS)]
+        s = 1 if mode == "decode" else self.PS * self.N_PB
+        xs = [jnp.asarray(rng.normal(size=(b, s, d)), jnp.float32)
+              for _ in range(self.LAYERS)]
+        layers = [{w: {"w": jnp.asarray(rng.normal(size=shape) * 0.2,
+                                        jnp.float32)}
+                   for w, shape in (("wq", (d, cfg.h_eff * hd)),
+                                    ("wk", (d, hkv * hd)),
+                                    ("wv", (d, hkv * hd)),
+                                    ("wo", (cfg.h_eff * hd, d)))}
+                  for _ in range(self.LAYERS)]
+        step = jax.jit(functools.partial(
+            blocks.attention_layer, cfg=cfg, mode=mode,
+            kind="local" if window else "full"))
+
+        def layer(j, cache, tbl):
+            return step(layers[j], xs[j], cache=cache, pos=lens,
+                        tables=jnp.asarray(tbl))
+
+        with pops.force_impl(impl):
+            own = [layer(j, {"k": jnp.asarray(pk_), "v": jnp.asarray(pv_)},
+                         tables)
+                   for j, (pk_, pv_) in enumerate(pools)]
+            flat = {"k": jnp.asarray(np.concatenate([p[0] for p in pools])),
+                    "v": jnp.asarray(np.concatenate([p[1] for p in pools]))}
+            for j in range(self.LAYERS):
+                before = {k: np.asarray(v) for k, v in flat.items()}
+                y, flat = layer(j, flat, lm.layer_tables(tables, j, n1))
+                # real rows: a null row's and prefill padding's outputs
+                # are discarded garbage read from the null page
+                for bi, n in enumerate(lens):
+                    rows = slice(0, 1 if mode == "decode" else n)
+                    if n:
+                        np.testing.assert_array_equal(
+                            np.asarray(y)[bi, rows],
+                            np.asarray(own[j][0])[bi, rows])
+                for k in ("k", "v"):
+                    after = np.asarray(flat[k])
+                    # its own pages, null page aside, as in a pool of
+                    # its own
+                    np.testing.assert_array_equal(
+                        after[j * n1 + 1:(j + 1) * n1],
+                        np.asarray(own[j][1][k])[1:])
+                    # every other layer's pages untouched
+                    for o in range(self.LAYERS):
+                        if o != j:
+                            np.testing.assert_array_equal(
+                                after[o * n1 + 1:(o + 1) * n1],
+                                before[k][o * n1 + 1:(o + 1) * n1])

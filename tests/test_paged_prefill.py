@@ -32,6 +32,7 @@ from repro.kernels.paged_attention import prefill as pf
 from repro.nn import blocks
 
 import proptest as pt
+from paged_pools import random_pools
 
 # kernel vs its ref: the same f32 online softmax, but whether each page's
 # rescale-then-add (`l * corr + sum`, `acc * corr + pv`) is contracted
@@ -53,8 +54,7 @@ def make_case(rng, lens, *, s=None, h=4, hkv=2, hd=16, ps=8, n_pb=4,
         s = -(-max(max(lens), 1) // pops.PREFILL_Q) * pops.PREFILL_Q
     if n_pages is None:
         n_pages = b * n_pb
-    pool_k = rng.normal(size=(n_pages + 1, ps, hkv, hd)).astype(np.float32)
-    pool_v = rng.normal(size=(n_pages + 1, ps, hkv, hd)).astype(np.float32)
+    pool_k, pool_v = random_pools(rng, n_pages, ps, hkv, hd)
     if poison_null:
         pool_k[0] = np.nan
         pool_v[0] = np.nan

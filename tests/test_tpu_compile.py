@@ -6,23 +6,35 @@ lowering, which refuses what interpret mode accepts (an
 that is not tile-aligned, too much VMEM).  These tests compile each
 kernel at real serving widths for a v5e that is described, not
 attached, and check that the program holds the kernel
-(``tpu_custom_call``).  Nothing runs, so nothing here says anything about
-results or speed.
+(``tpu_custom_call``).  They also compile the serving engine's paged
+decode and prefill programs and check that no op in them writes a
+buffer the size of a layer's page pool other than the in-place page
+writes: the pool is addressed where it lies, never sliced, restacked or
+relaid out.  Nothing runs, so nothing here says anything about results
+or speed.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import registry
 from repro.kernels.paged_attention import kernel as pk
+from repro.kernels.paged_attention import ops as pops
 from repro.kernels.paged_attention import prefill as pf
 from repro.kernels.quant_matmul import kernel as qk
+from repro.kernels.quant_matmul import ops as qops
+from repro.models import lm
+from repro.serve import engine
 
 PAGE = 16
 N_PAGES = 64
@@ -74,7 +86,7 @@ ATTN_CASES = {
 def test_paged_decode_compiles(one_chip, case):
     c = dict(ATTN_CASES[case])
     h, hkv, d, dt = c.pop("h"), c.pop("hkv"), c.pop("d"), c.pop("dtype")
-    pool = spec(one_chip, (N_PAGES + 1, PAGE, hkv, d), dt)
+    pool = spec(one_chip, (N_PAGES + 1, PAGE, hkv * d), dt)
     text = compile_text(
         functools.partial(pk.paged_attention_fwd, interpret=False, **c),
         spec(one_chip, (4, h, d), dt), pool, pool,
@@ -87,7 +99,7 @@ def test_paged_decode_compiles(one_chip, case):
 def test_paged_prefill_compiles(one_chip, case):
     c = dict(ATTN_CASES[case])
     h, hkv, d, dt = c.pop("h"), c.pop("hkv"), c.pop("d"), c.pop("dtype")
-    pool = spec(one_chip, (N_PAGES + 1, PAGE, hkv, d), dt)
+    pool = spec(one_chip, (N_PAGES + 1, PAGE, hkv * d), dt)
     text = compile_text(
         functools.partial(pf.paged_prefill_fwd, interpret=False,
                           q_chunk=16, **c),
@@ -113,3 +125,109 @@ def test_quant_matmul_compiles(one_chip, bits, m):
         spec(one_chip, (1, n), jnp.float32),
         spec(one_chip, (1, 1), jnp.float32))
     assert "tpu_custom_call" in text
+
+
+# --- the engine's paged programs hold no whole-pool copy ------------------
+
+SERVE_PAGES = 1024
+_DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2,
+                "s32": 4, "u32": 4, "f32": 4, "s64": 8}
+# ops that may carry a whole pool: the donated parameters, tuples of
+# them, reinterpretations, the scanned runner's loop, and the in-place
+# page writes (a scatter, alone or as a fusion's root)
+_POOL_OPS = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
+             "scatter"}
+
+
+def _largest_output(shape: str) -> int:
+    sizes = [int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+             * _DTYPE_BYTES[dt]
+             for dt, dims in re.findall(r"\b([a-z]+\d*)\[([\d,]*)\]", shape)
+             if dt in _DTYPE_BYTES]
+    return max(sizes, default=0)
+
+
+def pool_sized_ops(hlo: str, pool_bytes: int) -> list:
+    """``(computation, opcode, name)`` of every op outside a fusion body
+    that outputs a buffer of at least ``pool_bytes`` and is not in
+    ``_POOL_OPS``: a copy, slice, dynamic-slice, concatenate, relayout or
+    fusion (other than a scatter's) that moves a whole pool."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{\s*$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif cur is not None and line.strip().startswith(("%", "ROOT")):
+            cur.append(line.strip())
+    fused = {c for lines in comps.values() for line in lines
+             for c in re.findall(r"calls=%?([\w.\-]+)", line)}
+    found = []
+    for comp, lines in comps.items():
+        if comp in fused:
+            continue
+        for line in lines:
+            op = re.match(r"(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(",
+                          line)
+            if op is None or _largest_output(op.group(2)) < pool_bytes:
+                continue
+            name, _, code = op.groups()
+            if code == "fusion":
+                body = comps[re.search(r"calls=%?([\w.\-]+)",
+                                       line).group(1)]
+                if any(" scatter(" in b for b in body):
+                    continue
+            if code not in _POOL_OPS:
+                found.append((comp, code, name))
+    return found
+
+
+def _abstract(tree, sharding):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                       sharding=sharding), tree)
+
+
+@pytest.fixture(scope="module")
+def two_layer_lm():
+    """llama3.2-1b's pattern at two layers, 4/2 heads of 64: the
+    unaligned head width (64 of a 128-lane tile)."""
+    cfg = dataclasses.replace(registry.get("llama3.2-1b-smoke"),
+                              head_dim=64)
+    return cfg, lm.init_params(cfg, jax.random.key(0))
+
+
+@pytest.mark.parametrize("program", ["decode_greedy", "prefill_paged"])
+@pytest.mark.parametrize("runner", ["scanned-float", "unrolled-plan"])
+def test_paged_programs_hold_no_pool_copy(one_chip, two_layer_lm,
+                                          monkeypatch, runner, program):
+    cfg, params = two_layer_lm
+    plan = engine.synthetic_plan(cfg, params, seed=0) \
+        if runner == "unrolled-plan" else None
+    server = engine.InferenceServer(cfg, params, plan, cache="paged",
+                                    max_len=256, max_batch=4,
+                                    page_size=PAGE, pages=SERVE_PAGES)
+    be = server.backend
+    # the engine picks its kernels by the default backend, which here is
+    # the CPU: steer it to the TPU kernels for this compile
+    monkeypatch.setattr(pops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(qops, "_on_tpu", lambda: True)
+    args = {
+        "decode_greedy": lambda: server._decode_greedy.lower(
+            *_abstract((server.params, {"tokens": np.zeros((4, 1),
+                                                           np.int32)},
+                        be.gather(), be.device_tables(),
+                        np.zeros((4,), np.int32)), one_chip),
+            be.table_width),
+        "prefill_paged": lambda: server._prefill_paged.lower(
+            *_abstract((server.params, {"tokens": np.zeros((1, 64),
+                                                           np.int32)},
+                        be.kv_caches(), be.device_tables(), np.int32(0),
+                        np.asarray([60], np.int32)), one_chip),
+            64 // PAGE),
+    }
+    hlo = args[program]().compile().as_text()
+    assert "tpu_custom_call" in hlo
+    k_pool = be.kv_caches()["l0"]["kv"]["k"]
+    layer_bytes = k_pool[0].size * k_pool.dtype.itemsize
+    assert layer_bytes >= SERVE_PAGES * PAGE * cfg.head_dim * 2
+    assert pool_sized_ops(hlo, layer_bytes) == []
