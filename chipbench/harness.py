@@ -87,13 +87,36 @@ def load_cell(workload: str) -> dict:
     def applies(m):
         return "workloads" not in m or workload in m["workloads"]
 
-    return {"cell": cell,
-            "conf": model_mod.load_config(cell["config"]),
+    conf = model_mod.load_config(cell["config"])
+    return {"cell": cell, "conf": conf, **config_modules(conf),
             "mix": traffic_mod.load_mix(cell["traffic"]),
             "limits": json.loads(
                 (HERE / "limits" / f"{workload}.json").read_text()),
             "end_to_end": [m for m in bench["end_to_end"] if applies(m)],
             "per_layer": [m for m in bench["per_layer"] if applies(m)]}
+
+
+def _load(path: pathlib.Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def config_modules(conf: dict) -> dict:
+    """The configuration's plain reference and work counts: the modules
+    ``<name>.py`` of this directory that its keys ``"reference"`` and
+    ``"work"`` name, ``reference.py`` and ``work.py`` where a key is
+    absent."""
+    out = {}
+    for key in ("reference", "work"):
+        name = conf.get(key, key)
+        path = HERE / f"{name}.py"
+        if not name.isidentifier() or not path.is_file():
+            raise ValueError(f"configuration {conf['name']!r}: {key} "
+                             f"{name!r} is no module of {HERE}")
+        out[key] = _load(path, f"config_{key}_{name}")
+    return out
 
 
 def device_info(chips: int, require_tpu: bool) -> dict:
@@ -278,15 +301,9 @@ def ramp(server, mix: dict, gen, walks: list) -> tuple:
 
 def layer_readers(names) -> dict:
     """``layer_metrics/<name>.py`` for each per-layer metric, by name."""
-    out = {}
-    for name in names:
-        path = HERE / "layer_metrics" / f"{name}.py"
-        spec = importlib.util.spec_from_file_location(
-            f"layer_metric_{len(out)}", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        out[name] = mod
-    return out
+    return {name: _load(HERE / "layer_metrics" / f"{name}.py",
+                        f"layer_metric_{i}")
+            for i, name in enumerate(names)}
 
 
 def run(spec: dict, seed: int, seconds: float, traced: bool, *,
@@ -300,8 +317,9 @@ def run(spec: dict, seed: int, seconds: float, traced: bool, *,
     cell, conf, mix = spec["cell"], spec["conf"], spec["mix"]
     device = device_info(cell["chips"], require_tpu)
     clog = CompileLog()
+    ref = spec["reference"]
     cfg = model_mod.arch(conf)
-    bits = (model_mod.plan_bits(cfg, conf["plan"]) if conf["plan"]
+    bits = (model_mod.plan_bits(cfg, conf["plan"], ref) if conf["plan"]
             else None)
 
     t = time.perf_counter()
@@ -375,7 +393,8 @@ def run(spec: dict, seed: int, seconds: float, traced: bool, *,
         tr = trace_mod.reduce_dir(tdir, SPAN)
         shutil.rmtree(tdir, ignore_errors=True)
         ctx = trace_mod.Context(trace=tr, steps=window, cfg=cfg,
-                                bits=bits, peaks=work.peaks(device["kind"]))
+                                bits=bits, peaks=work.peaks(device["kind"]),
+                                work=spec["work"])
         readers = layer_readers([m["name"] for m in spec["per_layer"]])
         units = {m["name"]: m["unit"] for m in spec["per_layer"]}
         for name, mod in readers.items():
@@ -399,7 +418,7 @@ def run(spec: dict, seed: int, seconds: float, traced: bool, *,
     t = time.perf_counter()
     params = model_mod.make_params(cfg, seed)
     sample = oracle.sample(finished, mix["check_requests"], seed)
-    gap, ctl = (oracle.gaps(params, bits, sample, cfg, control=control)
+    gap, ctl = (oracle.gaps(params, bits, sample, cfg, ref, control=control)
                 if sample else (None, None))
     short = sum(1 for r in sample if len(r.tokens) != r.max_tokens)
     limit = spec["limits"]["logit_gap"]

@@ -3,8 +3,6 @@ is the live K and V of every decoded row's context, with its query and
 output, over the memory peak; the time is that of the decode kernel's
 device events.  Moves ``output_tok_s``."""
 
-import work
-
 # the paged decode kernel: block tables (B, P), positions (B,), queries
 # (B, H, D) -- the prefill kernel's queries are (1, S, H, D)
 KERNEL = (r"custom-call\(s32\[\d+,\d+\]\{[^}]*\} %[\w.\-]+, "
@@ -13,7 +11,7 @@ KERNEL = (r"custom-call\(s32\[\d+,\d+\]\{[^}]*\} %[\w.\-]+, "
 
 def read(ctx):
     _, secs = ctx.trace.kernel(KERNEL)
-    byts = sum(work.paged_decode_bytes(ctx.cfg, s.decode_ctx)
+    byts = sum(ctx.work.paged_decode_bytes(ctx.cfg, s.decode_ctx)
                for s in ctx.steps if s.decode_ctx)
     if secs <= 0 or byts == 0:
         return None

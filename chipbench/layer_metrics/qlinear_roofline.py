@@ -4,8 +4,6 @@ call of the traced steps, on the real rows of each call (decoded rows of
 a decode step, the prompt of a prefill); the time is that of the
 ``quant_matmul`` kernel's device events.  Moves ``output_tok_s``."""
 
-import work
-
 # the quant_matmul kernel: an f32 result from int8 operands
 KERNEL = r"= f32\[\d+,\d+\]\{[^}]*\} custom-call\(s8\["
 
@@ -21,5 +19,5 @@ def read(ctx):
         rows.extend(s.prefill)
         if s.decode_ctx:
             rows.append(len(s.decode_ctx))
-    least = work.qlinear_roofline_s(ctx.cfg, ctx.bits, rows, ctx.peaks)
+    least = ctx.work.qlinear_roofline_s(ctx.cfg, ctx.bits, rows, ctx.peaks)
     return 100.0 * least / secs

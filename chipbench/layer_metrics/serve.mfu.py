@@ -4,11 +4,10 @@ prunes left out and attention over each token's own context -- as a
 percent of the chip's bf16 peak over the window.  Moves
 ``output_tok_s``, and bounds every kernel's share from above."""
 
-import work
-
 
 def read(ctx):
-    flops = sum(work.model_flops(ctx.cfg, ctx.bits, s.prefill, s.decode_ctx)
+    flops = sum(ctx.work.model_flops(ctx.cfg, ctx.bits, s.prefill,
+                                     s.decode_ctx)
                 for s in ctx.steps)
     window_s = ctx.trace.window_s
     if flops <= 0 or window_s <= 0:

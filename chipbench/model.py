@@ -95,16 +95,19 @@ def plan_groups(cfg) -> dict:
     return groups
 
 
-def plan_bits(cfg, spec: dict) -> dict:
+def plan_bits(cfg, spec: dict, ref=None) -> dict:
     """Per-channel bits for every plan group, drawn from the plan seed
     with probabilities ``p`` over ``pw`` (fixed by the configuration, so
-    every run serves the same plan)."""
+    every run serves the same plan).  The groups are the reference
+    module ``ref``'s ``plan_groups`` where it has one, else
+    :func:`plan_groups`, drawn in sorted order."""
+    groups = getattr(ref, "plan_groups", plan_groups)(cfg)
     pw = np.asarray(spec["pw"], np.int64)
     p = np.asarray(spec["p"], np.float64)
     p = p / p.sum()
     rng = np.random.default_rng(int(spec["seed"]))
     return {g: rng.choice(pw, size=n, p=p).astype(np.int64)
-            for g, n in sorted(plan_groups(cfg).items())}
+            for g, n in sorted(groups.items())}
 
 
 def make_plan(conf: dict, bits: dict):
@@ -115,15 +118,3 @@ def make_plan(conf: dict, bits: dict):
         meta={"track": "lm", "arch": conf["arch"], "synthetic": True,
               "seed": conf["plan"]["seed"]})
 
-
-def stacked_bits(bits: dict, n_layers: int) -> dict:
-    """``{"mixer.wq": (n_layers, N) int32, ...}`` from plan groups named
-    ``blocks.l0.<path>.sb<j>`` (one-layer super-blocks)."""
-    out = {}
-    for g, b in bits.items():
-        _, lname, *mid, sb = g.split(".")
-        if lname != "l0":
-            raise ValueError(f"expected one layer per super-block: {g}")
-        out.setdefault(".".join(mid), {})[int(sb[2:])] = b
-    return {k: np.stack([v[j] for j in range(n_layers)]).astype(np.int32)
-            for k, v in out.items()}

@@ -8,7 +8,10 @@ one that ties it to within bf16 rounding, so a sound run's widest gap is
 small; a wrong token reads the spread of the logits.
 
 The control reads the same gap for the token that the reference computed
-one precision lower (``reference.hidden(..., lowp=True)``) puts first.
+one precision lower (``hidden(..., lowp=True)``) puts first.
+
+The reference is the configuration's module (``reference.py`` unless
+the configuration names another; see its contract there), passed in.
 """
 from __future__ import annotations
 
@@ -17,9 +20,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-import model as model_mod
-import reference
 
 SEQ_BUCKET = 512        # reference sequences are padded to a multiple
 ROW_CHUNK = 256         # logit rows computed at once
@@ -38,31 +38,27 @@ def sample(finished: list, k: int, seed: int) -> list:
     return [order[0]] + [rest[i] for i in sorted(pick)]
 
 
-def dims(cfg) -> tuple:
-    return (cfg.n_heads, cfg.head_dim, cfg.norm_eps, cfg.rope_theta)
-
-
-@functools.partial(jax.jit, static_argnames=("vocab", "with_control"))
-def _gaps(params, h, hc, tok, *, vocab, with_control):
-    lg = reference.logits(params, h, vocab=vocab)
+@functools.partial(jax.jit, static_argnames=("ref", "vocab", "with_control"))
+def _gaps(params, h, hc, tok, *, ref, vocab, with_control):
+    lg = ref.logits(params, h, vocab=vocab)
     best = jnp.max(lg, axis=-1)
     got = jnp.take_along_axis(lg, tok[:, None], axis=-1)[:, 0]
     if not with_control:
         return best - got, best - got
-    lc = reference.logits(params, hc, vocab=vocab, lowp=True)
+    lc = ref.logits(params, hc, vocab=vocab, lowp=True)
     first = jnp.argmax(lc, axis=-1)
     ctl = jnp.take_along_axis(lg, first[:, None], axis=-1)[:, 0]
     return best - got, best - ctl
 
 
-def gaps(params, bits, reqs: list, cfg, control: bool = False):
-    """Widest gap of the served tokens of ``reqs`` and, with
-    ``control``, of the control's first choices at the same positions.
-    Returns ``(served_gap, control_gap or None)``."""
-    sb = None if bits is None else {
-        k: jnp.asarray(v) for k, v in
-        model_mod.stacked_bits(bits, cfg.n_layers).items()}
-    dm = dims(cfg)
+def gaps(params, bits, reqs: list, cfg, ref, control: bool = False):
+    """Widest gap of the served tokens of ``reqs`` under the reference
+    module ``ref`` and, with ``control``, of the control's first choices
+    at the same positions.  Returns ``(served_gap, control_gap or
+    None)``."""
+    sb = (None if bits is None
+          else jax.tree.map(jnp.asarray, ref.stack_bits(bits, cfg)))
+    dm = ref.dims(cfg)
     served, ctl = 0.0, None if not control else 0.0
     for r in reqs:
         toks = np.asarray(r.tokens, np.int32)
@@ -70,16 +66,16 @@ def gaps(params, bits, reqs: list, cfg, control: bool = False):
         s = len(seq)
         pad = -(-s // SEQ_BUCKET) * SEQ_BUCKET
         seq = np.pad(seq, (0, pad - s))
-        h = reference.hidden(params, sb, jnp.asarray(seq), dims=dm)
-        hc = (reference.hidden(params, sb, jnp.asarray(seq), dims=dm,
-                               lowp=True) if control else h)
+        h = ref.hidden(params, sb, jnp.asarray(seq), dims=dm)
+        hc = (ref.hidden(params, sb, jnp.asarray(seq), dims=dm, lowp=True)
+              if control else h)
         pos = np.arange(len(r.prompt) - 1, s)          # one per served token
         for c in range(0, len(pos), ROW_CHUNK):
             p = pos[c:c + ROW_CHUNK]
             n = len(p)
             p = np.pad(p, (0, ROW_CHUNK - n))
             t = np.pad(toks[c:c + ROW_CHUNK], (0, ROW_CHUNK - n))
-            g, gc = _gaps(params, h[p], hc[p], jnp.asarray(t),
+            g, gc = _gaps(params, h[p], hc[p], jnp.asarray(t), ref=ref,
                           vocab=cfg.vocab, with_control=control)
             served = max(served, float(np.max(np.asarray(g)[:n])))
             if control:

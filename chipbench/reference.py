@@ -27,6 +27,18 @@ activations ``sx = max|x_row| / 127``, ``xq = clip(round(x / sx))``;
 ``lowp=True`` is the control: the same equations with every matmul
 operand rounded to float8 (e4m3), one step below the bf16 the
 configuration serves in, and planned projections' activations at int4.
+
+This is the dense decoder's module of the reference contract, which
+every reference module keeps (a configuration names its module by the
+key ``"reference"``; this one when the key is absent):
+
+    dims(cfg)                  static sizes ``hidden`` takes
+    stack_bits(bits, cfg)      plan groups -> this module's layer stacking
+    hidden(params, bits, tokens, *, dims, lowp=False)
+    logits(params, h, *, vocab, lowp=False)
+
+and may add ``plan_groups(cfg)`` where its plan covers weights that
+``model.plan_groups`` does not walk.
 """
 from __future__ import annotations
 
@@ -35,10 +47,28 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 HI = jax.lax.Precision.HIGHEST
 PROJ = ("mixer.wq", "mixer.wk", "mixer.wv", "mixer.wo",
         "ffn.w_gate", "ffn.w_up", "ffn.w_down")
+
+
+def dims(cfg) -> tuple:
+    return (cfg.n_heads, cfg.head_dim, cfg.norm_eps, cfg.rope_theta)
+
+
+def stack_bits(bits: dict, cfg) -> dict:
+    """``{"mixer.wq": (n_layers, N) int32, ...}`` from plan groups named
+    ``blocks.l0.<path>.sb<j>`` (one-layer super-blocks)."""
+    out = {}
+    for g, b in bits.items():
+        _, lname, *mid, sb = g.split(".")
+        if lname != "l0":
+            raise ValueError(f"expected one layer per super-block: {g}")
+        out.setdefault(".".join(mid), {})[int(sb[2:])] = b
+    return {k: np.stack([v[j] for j in range(cfg.n_layers)]).astype(np.int32)
+            for k, v in out.items()}
 
 
 def _low(x, lowp):

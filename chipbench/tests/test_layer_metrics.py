@@ -49,7 +49,7 @@ def table():
 
 def ctx_of(events):
     return Context(trace=reduce_events(events, "chipbench.step"),
-                   steps=[], cfg=None, bits=None, peaks={})
+                   steps=[], cfg=None, bits=None, peaks={}, work=None)
 
 
 def test_host_ms_is_the_step_less_its_device_waits():

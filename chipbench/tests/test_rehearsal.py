@@ -1,10 +1,12 @@
-"""A whole run of each cell's path at a tiny size on the CPU.
+"""A whole run of each configuration's path at a tiny size on the CPU.
 
 The harness's look for a chip is skipped (``require_tpu=False``); the
 rest of a run -- seeded weights and plan, the server, warm-up, ramp,
-window, drain and the check against the plain reference -- runs as on
-the chip, with the smoke-sized sibling of the configuration.  Then the
-timed path is broken underneath and the check has to fail.
+window, drain and the check against the configuration's own plain
+reference -- runs as on the chip, with the smoke-sized sibling of the
+configuration's architecture (``<arch>-smoke``).  Then the timed path is
+broken underneath and the check has to fail.  Every file in
+``configs/`` is rehearsed, so a new configuration needs no edit here.
 """
 import copy
 import time
@@ -13,6 +15,8 @@ import pytest
 
 import harness
 import model
+
+CONFIGS = sorted(p.stem for p in (harness.HERE / "configs").glob("*.json"))
 
 SMOKE_MIX = {"loop": "closed", "clients": 4, "max_batch": 4, "max_len": 64,
              "page_size": 16, "pages": 16, "prompt_lengths": [16, 32],
@@ -23,11 +27,12 @@ LIMIT = 0.02     # smoke size: the served tokens' widest gap, see below
 
 def smoke_spec(config: str) -> dict:
     conf = copy.deepcopy(model.load_config(config))
-    conf["arch"] = "minicpm-2b-smoke"
+    conf["arch"] += "-smoke"
     conf["overrides"] = {"param_dtype": "bfloat16"}
     return {"cell": {"name": f"smoke.{config}", "config": config,
                      "traffic": "smoke", "chips": 1},
-            "conf": conf, "mix": dict(SMOKE_MIX),
+            "conf": conf, **harness.config_modules(conf),
+            "mix": dict(SMOKE_MIX),
             "limits": {"logit_gap": LIMIT},
             "end_to_end": [{"name": n, "unit": u} for n, u in (
                 ("output_tok_s", "tokens/s"), ("ttft_p95_ms", "ms"),
@@ -43,7 +48,7 @@ def run(config, seed=3, seconds=1.0):
     return out, lines
 
 
-@pytest.mark.parametrize("config", ["minicpm-2b-float", "minicpm-2b-mixed"])
+@pytest.mark.parametrize("config", CONFIGS)
 def test_run_is_correct(config):
     out, lines = run(config)
     assert out["correct"], (out["checks"], lines)
@@ -84,7 +89,7 @@ def _state_unchanged(monkeypatch):
 
 
 @pytest.mark.parametrize("fault", [_altered_token, _state_unchanged])
-@pytest.mark.parametrize("config", ["minicpm-2b-float", "minicpm-2b-mixed"])
+@pytest.mark.parametrize("config", CONFIGS)
 def test_broken_path_is_not_correct(monkeypatch, config, fault):
     fault(monkeypatch)
     out, _ = run(config, seed=5)
@@ -92,7 +97,7 @@ def test_broken_path_is_not_correct(monkeypatch, config, fault):
     assert out["checks"]["logit_gap"]["value"] > LIMIT
 
 
-@pytest.mark.parametrize("config", ["minicpm-2b-float", "minicpm-2b-mixed"])
+@pytest.mark.parametrize("config", CONFIGS)
 def test_control_is_not_correct(config):
     out = harness.run(smoke_spec(config), 7, 1.0, False,
                       t_start=time.perf_counter(), require_tpu=False,

@@ -197,9 +197,14 @@ def reduce_dir(directory: str, span: str) -> Reduced:
 @dataclasses.dataclass
 class Context:
     """What a per-layer reader gets: the reduced trace, the harness's
-    records of the traced steps, the model's sizes and plan, the peaks."""
+    records of the traced steps, the model's sizes and plan, the peaks,
+    and the configuration's work module (``work.py`` unless the
+    configuration names another), which counts the model's operations
+    and bytes: ``model_flops``, ``qlinear_roofline_s`` and
+    ``paged_decode_bytes``."""
     trace: Reduced
     steps: list
     cfg: object
     bits: dict | None
     peaks: dict
+    work: object

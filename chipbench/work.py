@@ -3,6 +3,12 @@
 Counted from the model's sizes and the plan, never from a kernel's padded
 blocks, so any implementation of a layer is charged the same work.  The
 peaks come from ``peaks.json``, keyed by ``device_kind``.
+
+This is the dense decoder's module of the work contract, which every
+work module keeps (a configuration names its module by the key
+``"work"``; this one when the key is absent): ``model_flops``,
+``qlinear_roofline_s`` and ``paged_decode_bytes``, with this file's
+signatures.  ``peaks`` belongs to the device and stays here.
 """
 from __future__ import annotations
 
