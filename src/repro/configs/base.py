@@ -23,27 +23,36 @@ class ArchConfig:
     local_window: int = 0              # window for local/chunked attention
     attn_pattern: str = "full"         # full | local_global | chunked
     rope_theta: float = 10_000.0
+    use_rope: bool = True              # False: no position embedding (NoPE)
+    attn_scale: float = 0.0            # softmax scale (0 -> 1/sqrt(head_dim))
     # --- MoE ---
     n_experts: int = 0
     experts_per_token: int = 0
     moe_every: int = 1                 # MoE on every k-th layer
     dense_residual: bool = False       # dense FFN in parallel with MoE
     moe_d_ff: int = 0                  # expert hidden dim (0 -> d_ff)
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25      # 0 -> dropless (no capacity)
+    moe_renorm: bool = False           # softmax over the top-k logits only
+    n_experts_held: int = 0            # experts this layer holds (0 -> all):
+                                       # one expert-parallel shard's share
     # --- SSM / hybrid ---
-    attn_every: int = 0                # jamba: 1 attention layer per N
+    attn_every: int = 0                # hybrid: 1 attention layer per N
     ssm_state: int = 0
     ssm_expand: int = 2
     ssm_head_dim: int = 64
     ssm_chunk: int = 256
     ssm_conv: int = 4
+    ssm_conv_bias: bool = False
     # --- encoder-decoder ---
     enc_layers: int = 0
     # --- modality frontend stub ---
     frontend: str = "none"             # none | audio | vision
     # --- numerics / scale ---
     norm_eps: float = 1e-6
-    tie_embeddings: bool = False
+    tie_embeddings: bool = False       # output head = embedding^T
+    embed_scale: float = 0.0           # x0 = E[t] * it (0 -> sqrt(d_model))
+    residual_scale: float = 1.0        # x += it * branch, on every branch
+    logits_scaling: float = 1.0        # logits are divided by it
     param_dtype: str = "float32"       # master weights
     optimizer: str = "adam"            # adam | adam_int8
     remat: bool = True
@@ -95,6 +104,10 @@ class ArchConfig:
     @property
     def expert_d_ff(self) -> int:
         return self.moe_d_ff or self.d_ff
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)

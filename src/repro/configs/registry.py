@@ -82,6 +82,22 @@ QWEN2_VL_72B = ArchConfig(
     param_dtype="bfloat16", optimizer="adam_int8", train_microbatches=4,
 )
 
+# ibm-granite/granite-4.0-h-small (config.json, model_type
+# granitemoehybrid): Mamba-2 layers with one NoPE GQA layer in ten, a
+# 72-expert top-10 MoE plus a shared expert after every mixer
+GRANITE_4_0_H_SMALL = ArchConfig(
+    name="granite-4.0-h-small", family="hybrid",
+    n_layers=40, d_model=4096, n_heads=32, n_kv_heads=8, d_ff=1536,
+    vocab=100352, head_dim=128, use_rope=False, attn_scale=1 / 128,
+    n_experts=72, experts_per_token=10, moe_every=1, moe_d_ff=768,
+    dense_residual=True, capacity_factor=0.0, moe_renorm=True,
+    attn_every=10, ssm_state=128, ssm_expand=2,
+    ssm_head_dim=64, ssm_chunk=256, ssm_conv=4, ssm_conv_bias=True,
+    norm_eps=1e-5, tie_embeddings=True, embed_scale=12.0,
+    residual_scale=0.22, logits_scaling=16.0,
+    param_dtype="bfloat16", optimizer="adam_int8", train_microbatches=4,
+)
+
 # beyond-paper performance variants (Sec-Perf hillclimb): pad heads up to
 # a TP16-divisible count so attention shards instead of replicating
 MINICPM_2B_PADHEADS = dataclasses.replace(
@@ -97,6 +113,7 @@ ARCHS: dict[str, ArchConfig] = {
         JAMBA_1_5_LARGE, MAMBA2_780M, QWEN3_32B, LLAMA32_1B, MINICPM_2B,
         GEMMA2_2B, SEAMLESS_M4T_MEDIUM, LLAMA4_SCOUT, ARCTIC_480B,
         QWEN2_VL_72B, MINICPM_2B_PADHEADS, GEMMA2_2B_PADHEADS,
+        GRANITE_4_0_H_SMALL,
     ]
 }
 
@@ -114,14 +131,15 @@ RULE_OVERRIDES: dict[str, dict] = {
     "arctic-480b": {"kv_heads": None},
     "qwen2-vl-72b": {"kv_heads": None},
     "jamba-1.5-large-398b": {"kv_heads": None},
+    "granite-4.0-h-small": {"kv_heads": None},
 }
 
 
 def reduced(cfg: ArchConfig) -> ArchConfig:
-    """Tiny same-family variant for CPU smoke tests."""
-    pat_len = {"hybrid": 8, "ssm": 1, "dense": 2 if cfg.attn_pattern ==
-               "local_global" else 1, "moe": 4 if cfg.attn_pattern ==
-               "chunked" else 1, "encdec": 1, "vlm": 1}[cfg.family]
+    """Tiny same-family variant for CPU smoke tests: one super-block of
+    the layer pattern (two where it is one or two layers long)."""
+    from repro.models import lm
+    pat_len = len(lm.block_pattern(cfg))
     return dataclasses.replace(
         cfg,
         name=cfg.name + "-smoke",
@@ -133,7 +151,7 @@ def reduced(cfg: ArchConfig) -> ArchConfig:
         d_ff=128 if cfg.d_ff else 0,
         moe_d_ff=64 if cfg.moe_d_ff else 0,
         vocab=512,
-        n_experts=4 if cfg.n_experts else 0,
+        n_experts=4 if cfg.n_experts else 0, n_experts_held=0,
         experts_per_token=min(cfg.experts_per_token, 2),
         ssm_state=16 if cfg.ssm_state else 0,
         ssm_head_dim=16 if cfg.ssm_state else 64,

@@ -1,8 +1,9 @@
 """Generic LM covering all 10 assigned architectures.
 
 One decoder implementation parameterized by ArchConfig:
-  * layer "super-block" patterns (dense, local/global, chunked+MoE, jamba
-    1:7 mamba:attn with alternating MoE, pure SSM, enc-dec)
+  * layer "super-block" patterns (dense, local/global, chunked+MoE,
+    hybrid mamba:attn with MoE every moe_every-th layer (jamba 7:1,
+    granite 9:1), pure SSM, enc-dec)
   * jax.lax.scan over super-blocks (HLO size independent of depth) with
     optional remat
   * the paper's channel-wise MPS + pruning as a first-class mode: every
@@ -48,13 +49,15 @@ class LayerSpec:
 
 def block_pattern(cfg: ArchConfig) -> tuple[LayerSpec, ...]:
     """Decoder super-block pattern; n_layers % len(pattern) == 0."""
-    if cfg.is_hybrid:  # jamba: 1:7 attn:mamba, MoE every other layer
-        out = []
-        for i in range(cfg.attn_every):
-            mixer = "attn" if i == cfg.attn_every // 2 else "mamba"
-            ffn = "moe" if (i % 2 == 1) else "dense"
-            out.append(LayerSpec(mixer, ffn))
-        return tuple(out)
+    if cfg.is_hybrid:
+        # one attention layer in attn_every, at attn_every // 2 (jamba 4
+        # of 8, granite 5 of 10), MoE on every moe_every-th layer (jamba
+        # 2, granite 1)
+        k = cfg.moe_every if cfg.is_moe else 0
+        return tuple(
+            LayerSpec("attn" if i == cfg.attn_every // 2 else "mamba",
+                      "moe" if k and i % k == k - 1 else "dense")
+            for i in range(cfg.attn_every))
     if cfg.is_ssm:
         return (LayerSpec("mamba", None),)
     if cfg.attn_pattern == "local_global":
@@ -146,9 +149,11 @@ def _ffn_params(b: _Builder, cfg: ArchConfig, nsb: int, d_ff: int):
 
 
 def _moe_params(b: _Builder, cfg: ArchConfig, nsb: int):
-    d, e, f = cfg.d_model, cfg.n_experts, cfg.expert_d_ff
+    """The router scores all ``n_experts``; the banks hold the layer's
+    ``experts_held`` (one expert-parallel shard's share)."""
+    d, e, f = cfg.d_model, cfg.experts_held, cfg.expert_d_ff
     p, l = {}, {}
-    rp, rl = b.w((d, e), (None, None), mps_ok=False, stack=nsb)
+    rp, rl = b.w((d, cfg.n_experts), (None, None), mps_ok=False, stack=nsb)
     p["router"], l["router"] = rp, rl
     p["w_gate"], l["w_gate"] = b.w((e, d, f),
                                    ("experts", "w_embed", None), stack=nsb)
@@ -177,6 +182,13 @@ def _mamba_params(b: _Builder, cfg: ArchConfig, nsb: int):
                                      stack=nsb)
     p["conv_b"], l["conv_b"] = b.vec((kk, n), (None, None), 0.1, stack=nsb)
     p["conv_c"], l["conv_c"] = b.vec((kk, n), (None, None), 0.1, stack=nsb)
+    if cfg.ssm_conv_bias:
+        p["conv_bias_x"], l["conv_bias_x"] = b.vec((di,), ("ssm_inner",),
+                                                   0.0, stack=nsb)
+        p["conv_bias_b"], l["conv_bias_b"] = b.vec((n,), (None,), 0.0,
+                                                   stack=nsb)
+        p["conv_bias_c"], l["conv_bias_c"] = b.vec((n,), (None,), 0.0,
+                                                   stack=nsb)
     p["dt_bias"], l["dt_bias"] = b.vec((h,), (None,), 0.0, stack=nsb)
     p["a_log"], l["a_log"] = b.vec((h,), (None,), 0.0, stack=nsb)
     p["d_skip"], l["d_skip"] = b.vec((h,), (None,), 1.0, stack=nsb)
@@ -221,8 +233,9 @@ def _build(cfg: ArchConfig, key, mps_on: bool):
         bp[f"l{i}"], bl[f"l{i}"] = _layer_params(b, cfg, spec, nsb)
     params["blocks"], logical["blocks"] = bp, bl
     params["final_norm"], logical["final_norm"] = b.vec((d,), (None,), 0.0)
-    params["lm_head"], logical["lm_head"] = b.w(
-        (d, v), ("w_embed", "vocab"), scale=0.02, mps_ok=False)
+    if not cfg.tie_embeddings:
+        params["lm_head"], logical["lm_head"] = b.w(
+            (d, v), ("w_embed", "vocab"), scale=0.02, mps_ok=False)
     if cfg.is_encdec:
         ep, el = {}, {}
         epat = enc_pattern(cfg)
@@ -299,11 +312,11 @@ def _layer_apply(cfg, spec: LayerSpec, p, x, *, mode, cache, pos,
     new_cache = {}
     h = blocks.rmsnorm(x, p["norm1"], cfg.norm_eps)
     if spec.mixer == "mamba":
-        amode = mode if mode != "train" else "train"
-        y, st = blocks.mamba2_layer(
-            p["mixer"], h, cfg, mode=amode,
-            state=None if cache is None else cache.get("mamba"),
-            effective_w=getw)
+        with jax.named_scope("mamba"):
+            y, st = blocks.mamba2_layer(
+                p["mixer"], h, cfg, mode=mode,
+                state=None if cache is None else cache.get("mamba"),
+                effective_w=getw)
         if st is not None:
             new_cache["mamba"] = st
     else:
@@ -315,7 +328,7 @@ def _layer_apply(cfg, spec: LayerSpec, p, x, *, mode, cache, pos,
                 pos=pos, effective_w=getw, tables=tables)
         if kv is not None:
             new_cache["kv"] = kv
-    x = x + y
+    x = x + _residual(cfg, y)
     if spec.cross and enc_out is not None:
         hc = blocks.rmsnorm(x, p["norm_cross"], cfg.norm_eps)
         with jax.named_scope("attn"):
@@ -329,13 +342,21 @@ def _layer_apply(cfg, spec: LayerSpec, p, x, *, mode, cache, pos,
         x = x + yc
     if spec.ffn is not None:
         h2 = blocks.rmsnorm(x, p["norm2"], cfg.norm_eps)
-        with jax.named_scope("ffn"):
-            if spec.ffn == "moe":
+        if spec.ffn == "moe":
+            with jax.named_scope("moe"):
                 y2 = blocks.moe_layer(p["ffn"], h2, cfg, effective_w=getw)
-            else:
+        else:
+            with jax.named_scope("ffn"):
                 y2 = blocks.ffn_swiglu(p["ffn"], h2, effective_w=getw)
-        x = x + y2
+        x = x + _residual(cfg, y2)
     return x, (new_cache or None)
+
+
+def _residual(cfg, y):
+    """A residual branch as it is added (``residual_scale`` times)."""
+    if cfg.residual_scale == 1.0:
+        return y
+    return y * jnp.asarray(cfg.residual_scale, y.dtype)
 
 
 def _split_pools(caches, tables):
@@ -522,7 +543,8 @@ def _embed_in(cfg, params, batch):
     else:
         table = params["embed"]["w"].astype(jnp.bfloat16)
         x = jnp.take(table, batch["tokens"], axis=0)
-        x = x * jnp.asarray(math.sqrt(cfg.d_model), jnp.bfloat16)
+        x = x * jnp.asarray(cfg.embed_scale or math.sqrt(cfg.d_model),
+                            jnp.bfloat16)
     return x.astype(jnp.bfloat16)
 
 
@@ -594,12 +616,26 @@ def forward(cfg: ArchConfig, params, batch, *, mode: str = "train",
             x = jax.lax.dynamic_slice_in_dim(x, jnp.asarray(last_pos), 1,
                                              axis=1)
     with jax.named_scope("lm_head"):
+        logits = _head(cfg, params, x)
+    return logits, new_caches
+
+
+def _head(cfg, params, x):
+    """Output logits of hidden ``x`` (B, S, d): the head (the embedding
+    transposed where they are tied), divided by ``logits_scaling``, then
+    soft-capped."""
+    if cfg.tie_embeddings:
+        logits = jnp.einsum("bsd,vd->bsv", x,
+                            params["embed"]["w"].astype(jnp.bfloat16))
+    else:
         logits = jnp.einsum("bsd,dv->bsv", x,
                             params["lm_head"]["w"].astype(jnp.bfloat16))
-        logits = sharding.constrain(logits, "batch", None, "vocab")
-        if cfg.final_softcap > 0:
-            logits = blocks.softcap(logits, cfg.final_softcap)
-    return logits, new_caches
+    logits = sharding.constrain(logits, "batch", None, "vocab")
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
+    if cfg.final_softcap > 0:
+        logits = blocks.softcap(logits, cfg.final_softcap)
+    return logits
 
 
 LOSS_SEQ_CHUNKS = 8
@@ -619,15 +655,10 @@ def loss_fn(cfg: ArchConfig, params, batch,
     hidden, _ = forward(cfg, params, batch, mode="train", ctx=ctx,
                         logits_mode="hidden")
     targets = batch["targets"]
-    head = params["lm_head"]["w"].astype(jnp.bfloat16)
 
     @jax.checkpoint
     def chunk_nll(x_c, tgt_c):
-        logits = jnp.einsum("bsd,dv->bsv", x_c, head)
-        logits = sharding.constrain(logits, "batch", None, "vocab")
-        if cfg.final_softcap > 0:
-            logits = blocks.softcap(logits, cfg.final_softcap)
-        logits = logits.astype(jnp.float32)
+        logits = _head(cfg, params, x_c).astype(jnp.float32)
         logz = jax.scipy.special.logsumexp(logits, axis=-1)
         tgt = jnp.take_along_axis(logits, tgt_c[..., None], axis=-1)[..., 0]
         return jnp.sum(logz - tgt)

@@ -11,11 +11,13 @@ Implemented here:
     logit softcap (gemma2), GQA, qk-norm (qwen3)
   * decode attention against a KV cache (seq-shardable)
   * SwiGLU FFN
-  * top-k MoE with capacity-based token dropping, expert-parallel via
+  * top-k MoE, capacity-based token dropping or dropless, over all of
+    the layer's experts or the share it holds, expert-parallel via
     shard_map over the 'model' axis (TP-style: activations replicated over
     'model', each shard computes its experts, one psum)
   * Mamba-2 SSD mixer (chunked dual form; inter-chunk pass via
-    repro.kernels.ssd_scan)
+    repro.kernels.ssd_scan; the decode step's state update via
+    repro.kernels.ssm_decode)
 """
 from __future__ import annotations
 
@@ -29,6 +31,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.distributed import sharding
 from repro.kernels.paged_attention import ops as paged_ops
+from repro.kernels.ssm_decode import ops as ssm_ops
 from repro.nn import quantized as nnq
 
 # ---------------------------------------------------------------------------
@@ -278,6 +281,12 @@ def attention_layer(p: dict, x: jax.Array, cfg, *, kind: str = "full",
     if cfg.qk_norm:
         q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
         kk = rmsnorm(kk, p["k_norm"], cfg.norm_eps)
+    if cfg.attn_scale:
+        # every attention path scales by 1/sqrt(hd); fold the rest into q
+        q = q * jnp.asarray(cfg.attn_scale * math.sqrt(hd), q.dtype)
+
+    def pe(t, pos):            # the position embedding (none under NoPE)
+        return rope(t, pos, cfg.rope_theta) if cfg.use_rope else t
 
     causal = kind not in ("bidir", "cross")
     window = cfg.local_window if kind in ("local", "chunked") else 0
@@ -298,8 +307,8 @@ def attention_layer(p: dict, x: jax.Array, cfg, *, kind: str = "full",
         # () pos: one shared position; (B,) pos: per-slot positions
         # (continuous batching), rope/cache-write/mask all row-wise.
         pos_rope = posn[None] if posn.ndim == 0 else posn[:, None]
-        q = rope(q, pos_rope, cfg.rope_theta)
-        kk = rope(kk, pos_rope, cfg.rope_theta)
+        q = pe(q, pos_rope)
+        kk = pe(kk, pos_rope)
         if cache is not None and tables is not None:
             # paged KV (serve.cache.PagedCache): cache["k"/"v"] are page
             # pools (n, page_size, hkv * hd), `tables` the per-slot
@@ -346,8 +355,8 @@ def attention_layer(p: dict, x: jax.Array, cfg, *, kind: str = "full",
                                    chunked=chunked, cap=cfg.attn_softcap)
     else:
         positions = jnp.arange(s)
-        q = rope(q, positions, cfg.rope_theta)
-        kk = rope(kk, positions, cfg.rope_theta)
+        q = pe(q, positions)
+        kk = pe(kk, positions)
         if mode == "prefill" and cache is not None and tables is not None:
             # paged prefill (serve.cache.PagedCache): cache["k"/"v"] are
             # page pools, `tables` the per-slot block tables (B, P), and
@@ -402,19 +411,43 @@ def ffn_swiglu(p: dict, x: jax.Array, effective_w=None) -> jax.Array:
     return linear(h, getw(p["w_down"]))
 
 
-def _moe_local(x, router_w, w_gate, w_up, w_down, *, n_experts: int,
-               top_k: int, capacity: int, e_offset):
-    """Per-shard MoE: x (T, D) local tokens; w_* (E_loc, ...) local experts.
+def _moe_local(x, router_w, w_gate, w_up, w_down, *, top_k: int,
+               capacity: Optional[int], e_offset, renorm: bool = False):
+    """Per-shard MoE: x (T, D) local tokens; w_* (E_loc, ...) the experts
+    this shard holds, numbered from ``e_offset`` among the router's
+    outputs.  Returns this shard's part of the result, (T, D).
 
-    Capacity-based dropping: each expert processes its top-`capacity`
-    local tokens by gate weight; overflow tokens are dropped (contribute 0
-    for that expert), matching Switch-style routing.
+    Routing: the top-k of the router's softmax, or with ``renorm`` a
+    softmax over the top-k logits alone (taken in f32).
+
+    ``capacity`` None is dropless: one masked dense pass over the held
+    experts, each row weighted by its gate for that expert (0 where the
+    expert was not chosen), so a row's result depends on no other row.
+    Otherwise each expert processes its top-``capacity`` local tokens by
+    gate weight; overflow tokens are dropped (contribute 0 for that
+    expert), matching Switch-style routing.
     """
     t, dm = x.shape
     e_loc = w_gate.shape[0]
-    logits = x @ router_w                                # (T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, ids = jax.lax.top_k(probs, top_k)             # (T, k)
+    with jax.named_scope("router"):
+        if renorm:
+            logits = jnp.dot(x, router_w,
+                             preferred_element_type=jnp.float32)
+            top, ids = jax.lax.top_k(logits, top_k)        # (T, k)
+            gates = jax.nn.softmax(top, axis=-1)
+        else:
+            logits = x @ router_w                          # (T, E)
+            probs = jax.nn.softmax(logits, axis=-1)
+            gates, ids = jax.lax.top_k(probs, top_k)       # (T, k)
+    if capacity is None:
+        held = e_offset + jnp.arange(e_loc)
+        g = jnp.sum(jnp.where(ids[:, :, None] == held, gates[:, :, None],
+                              0.0), axis=1).astype(jnp.float32)  # (T, E_loc)
+        hh = (jax.nn.silu(jnp.einsum("td,edf->etf", x, w_gate))
+              * jnp.einsum("td,edf->etf", x, w_up))
+        o = jnp.einsum("etf,efd->etd", hh, w_down).astype(jnp.float32)
+        y = jnp.sum(o * g.T[:, :, None], axis=0)
+        return y.astype(x.dtype)
     y = jnp.zeros((t, dm), jnp.float32)
     for el in range(e_loc):
         eg = e_offset + el
@@ -429,13 +462,19 @@ def _moe_local(x, router_w, w_gate, w_up, w_down, *, n_experts: int,
 
 
 def moe_layer(p: dict, x: jax.Array, cfg, effective_w=None) -> jax.Array:
-    """Top-k MoE over cfg.n_experts, experts sharded on 'model'."""
+    """Top-k MoE over cfg.n_experts, experts sharded on 'model'.
+
+    The banks hold ``cfg.experts_held`` experts, the router's first ones
+    (all of them unless the layer is one expert-parallel shard); a
+    ``capacity_factor`` of 0 routes dropless.  The shared expert
+    (``dense_residual``) is added once."""
     getw = effective_w or (lambda pp: pp["w"])
     b, s, dm = x.shape
     mesh = sharding.get_mesh()
     rules = sharding.get_rules() or {}
     e = cfg.n_experts
     k = cfg.experts_per_token
+    dropless = cfg.capacity_factor <= 0
     router_w = p["router"]["w"]
     wg, wu, wd = (getw(p["w_gate"]), getw(p["w_up"]), getw(p["w_down"]))
 
@@ -445,9 +484,10 @@ def moe_layer(p: dict, x: jax.Array, cfg, effective_w=None) -> jax.Array:
     batch_axes = rules.get("batch")
     if mesh is None or tp == 1:
         xx = x.reshape(b * s, dm)
-        cap = max(1, int(math.ceil(b * s * k * cfg.capacity_factor / e)))
-        y = _moe_local(xx, router_w, wg, wu, wd, n_experts=e, top_k=k,
-                       capacity=cap, e_offset=0)
+        cap = None if dropless else max(
+            1, int(math.ceil(b * s * k * cfg.capacity_factor / e)))
+        y = _moe_local(xx, router_w, wg, wu, wd, top_k=k, capacity=cap,
+                       e_offset=0, renorm=cfg.moe_renorm)
         out = y.reshape(b, s, dm)
     else:
         dp = 1
@@ -455,16 +495,17 @@ def moe_layer(p: dict, x: jax.Array, cfg, effective_w=None) -> jax.Array:
                    else (batch_axes,) if batch_axes else ()):
             dp *= mesh.shape[ax]
         t_loc = max(b // dp, 1) * s
-        cap = max(1, int(math.ceil(t_loc * k * cfg.capacity_factor / e)))
-        e_loc = e // tp
+        cap = None if dropless else max(
+            1, int(math.ceil(t_loc * k * cfg.capacity_factor / e)))
+        e_loc = cfg.experts_held // tp
         model_ax = rules["experts"]
 
         def shard_fn(xs, rw, wg_, wu_, wd_):
             t_b, t_s, t_d = xs.shape
             xx = xs.reshape(t_b * t_s, t_d)
             e_off = jax.lax.axis_index(model_ax) * e_loc
-            y = _moe_local(xx, rw, wg_, wu_, wd_, n_experts=e, top_k=k,
-                           capacity=cap, e_offset=e_off)
+            y = _moe_local(xx, rw, wg_, wu_, wd_, top_k=k, capacity=cap,
+                           e_offset=e_off, renorm=cfg.moe_renorm)
             y = jax.lax.psum(y, model_ax)
             return y.reshape(t_b, t_s, t_d)
 
@@ -477,7 +518,8 @@ def moe_layer(p: dict, x: jax.Array, cfg, effective_w=None) -> jax.Array:
         )(x, router_w, wg, wu, wd)
 
     if cfg.dense_residual:
-        out = out + ffn_swiglu(p["shared"], x, effective_w)
+        with jax.named_scope("shared_expert"):
+            out = out + ffn_swiglu(p["shared"], x, effective_w)
     return out
 
 
@@ -487,20 +529,24 @@ def moe_layer(p: dict, x: jax.Array, cfg, effective_w=None) -> jax.Array:
 
 
 def _causal_conv1d(x: jax.Array, w: jax.Array, mode: str,
-                   conv_state: Optional[jax.Array]):
-    """Depthwise causal conv. x: (B, S, C); w: (K, C).
-    Returns (y, new_conv_state (B, K-1, C))."""
+                   conv_state: Optional[jax.Array],
+                   bias: Optional[jax.Array] = None):
+    """Depthwise causal conv. x: (B, S, C); w: (K, C); bias: (C,) or
+    None.  Returns (y, new_conv_state (B, K-1, C))."""
     kk = w.shape[0]
     w = w.astype(x.dtype)   # bf16 compute (conv weights are tiny)
     if mode == "decode":
         window = jnp.concatenate([conv_state.astype(x.dtype), x], axis=1)
         y = jnp.einsum("bkc,kc->bc", window, w)[:, None, :]
-        return y, window[:, 1:, :]
-    pad = jnp.zeros((x.shape[0], kk - 1, x.shape[2]), x.dtype)
-    xp = jnp.concatenate([pad, x], axis=1)
-    y = sum(xp[:, i:i + x.shape[1]] * w[i][None, None, :]
-            for i in range(kk))
-    new_state = xp[:, xp.shape[1] - (kk - 1):, :]
+        new_state = window[:, 1:, :]
+    else:
+        pad = jnp.zeros((x.shape[0], kk - 1, x.shape[2]), x.dtype)
+        xp = jnp.concatenate([pad, x], axis=1)
+        y = sum(xp[:, i:i + x.shape[1]] * w[i][None, None, :]
+                for i in range(kk))
+        new_state = xp[:, xp.shape[1] - (kk - 1):, :]
+    if bias is not None:
+        y = y + bias.astype(x.dtype)
     return y, new_state
 
 
@@ -531,11 +577,14 @@ def mamba2_layer(p: dict, x: jax.Array, cfg, *, mode: str = "train",
 
     cst = None if state is None else state["conv"]
     xs_pre, ncx = _causal_conv1d(xs_pre, p["conv_x"], mode,
-                                 None if cst is None else cst["x"])
+                                 None if cst is None else cst["x"],
+                                 p.get("conv_bias_x"))
     bb_pre, ncb = _causal_conv1d(bb_pre, p["conv_b"], mode,
-                                 None if cst is None else cst["b"])
+                                 None if cst is None else cst["b"],
+                                 p.get("conv_bias_b"))
     cc_pre, ncc = _causal_conv1d(cc_pre, p["conv_c"], mode,
-                                 None if cst is None else cst["c"])
+                                 None if cst is None else cst["c"],
+                                 p.get("conv_bias_c"))
     new_conv = {"x": ncx, "b": ncb, "c": ncc}
     xs = jax.nn.silu(xs_pre).reshape(b, s, nh, hd)          # (B,S,H,P)
     bb = jax.nn.silu(bb_pre)                                # (B,S,N)
@@ -548,12 +597,10 @@ def mamba2_layer(p: dict, x: jax.Array, cfg, *, mode: str = "train",
     cc_f = cc.astype(jnp.float32)
 
     if mode == "decode":
-        s0 = state["ssm"]                                   # (B,H,P,N)
-        dec = jnp.exp(dta[:, 0])                            # (B,H)
-        upd = jnp.einsum("bh,bhp,bn->bhpn", dt[:, 0], xs_f[:, 0], bb_f[:, 0])
-        s_new = dec[..., None, None] * s0 + upd
-        y = jnp.einsum("bhpn,bn->bhp", s_new, cc_f[:, 0])
-        y = y + p["d_skip"].astype(jnp.float32)[None, :, None] * xs_f[:, 0]
+        # (B,H,P,N) state updated in place on TPU (kernels/ssm_decode)
+        y, s_new = ssm_ops.ssm_decode_step(
+            state["ssm"], xs_f[:, 0], dt[:, 0], a, bb_f[:, 0], cc_f[:, 0],
+            p["d_skip"].astype(jnp.float32))
         y = y.reshape(b, 1, di)
         new_state = {"ssm": s_new, "conv": new_conv}
     else:

@@ -33,7 +33,11 @@ from repro.kernels.paged_attention import ops as pops
 from repro.kernels.paged_attention import prefill as pf
 from repro.kernels.quant_matmul import kernel as qk
 from repro.kernels.quant_matmul import ops as qops
+from repro.kernels.ssm_decode import kernel as sk
+from repro.kernels.ssm_decode import ops as sops
+from repro.launch import steps
 from repro.models import lm
+from repro.nn.quantized import PackedLinear
 from repro.serve import engine
 
 PAGE = 16
@@ -147,10 +151,10 @@ def _largest_output(shape: str) -> int:
     return max(sizes, default=0)
 
 
-def pool_sized_ops(hlo: str, pool_bytes: int) -> list:
+def pool_sized_ops(hlo: str, pool_bytes: int, allowed=_POOL_OPS) -> list:
     """``(computation, opcode, name)`` of every op outside a fusion body
     that outputs a buffer of at least ``pool_bytes`` and is not in
-    ``_POOL_OPS``: a copy, slice, dynamic-slice, concatenate, relayout or
+    ``allowed``: a copy, slice, dynamic-slice, concatenate, relayout or
     fusion (other than a scatter's) that moves a whole pool."""
     comps, cur = {}, None
     for line in hlo.splitlines():
@@ -176,7 +180,7 @@ def pool_sized_ops(hlo: str, pool_bytes: int) -> list:
                                        line).group(1)]
                 if any(" scatter(" in b for b in body):
                     continue
-            if code not in _POOL_OPS:
+            if code not in allowed:
                 found.append((comp, code, name))
     return found
 
@@ -231,3 +235,115 @@ def test_paged_programs_hold_no_pool_copy(one_chip, two_layer_lm,
     layer_bytes = k_pool[0].size * k_pool.dtype.itemsize
     assert layer_bytes >= SERVE_PAGES * PAGE * cfg.head_dim * 2
     assert pool_sized_ops(hlo, layer_bytes) == []
+
+
+# --- granite-4.0-h-small: the Mamba-2 decode-state kernel and one stage --
+
+@pytest.mark.parametrize("shape", [(64, 128, 64, 128), (64, 128, 128, 128)],
+                         ids=["granite", "jamba"])
+def test_ssm_decode_compiles(one_chip, shape):
+    """The decode cell's 64 rows: granite's 128 heads of 64 and a
+    head_dim-128 (jamba) state, d_state 128."""
+    b, h, p, n = shape
+    f32 = jnp.float32
+    text = compile_text(
+        functools.partial(sk.ssm_decode_step_fwd, interpret=False),
+        spec(one_chip, shape, f32), spec(one_chip, (b, h, p), f32),
+        spec(one_chip, (b, h), f32), spec(one_chip, (h,), f32),
+        spec(one_chip, (b, n), f32), spec(one_chip, (b, n), f32),
+        spec(one_chip, (h,), f32))
+    assert "tpu_custom_call" in text and "ssm_decode_step" in text
+
+
+def granite_stage(n_layers=2):
+    """granite-4.0-h-small at full width, cut to a stage of Mamba-2
+    layers and one attention layer, each followed by its MoE of 9 held
+    experts and the shared expert."""
+    return dataclasses.replace(
+        registry.get("granite-4.0-h-small"), n_layers=n_layers,
+        attn_every=n_layers, n_experts_held=9)
+
+
+def abstract_served_params(cfg, runner, sharding):
+    """Abstract parameters as the server holds them: stacked float for
+    the scanned runner, or the unrolled tree of ``apply_plan`` with every
+    planned projection a ``PackedLinear`` of 0/2/4/8-bit channels in the
+    ratio 1:2:3:4."""
+    put = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=sharding)
+    tmpl = lm.abstract_params(cfg, mps_on=runner == "unrolled-plan")
+    params = {k: jax.tree.map(lambda a: put(a.shape, a.dtype), v)
+              for k, v in tmpl.items() if k != "blocks"}
+    if runner == "scanned-float":
+        params["blocks"] = jax.tree.map(lambda a: put(a.shape, a.dtype),
+                                        tmpl["blocks"])
+        return params
+
+    def bind(node):
+        if "gamma" in node and node["w"].ndim == 3:
+            k, n = node["w"].shape[1:]
+            counts = [(b, n * r // 10) for b, r in ((2, 2), (4, 3), (8, 4))]
+            return {"w": PackedLinear(
+                tuple((b, put((c, k * b // 8), jnp.int8),
+                       put((c,), jnp.float32)) for b, c in counts),
+                put((sum(c for _, c in counts),), jnp.int32), k, n)}
+        return {key: bind(v) if isinstance(v, dict)
+                else put(v.shape[1:], v.dtype)
+                for key, v in node.items() if key != "gamma"}
+
+    # every super-block's tree has the same shapes
+    params["blocks"] = tuple(bind(tmpl["blocks"])
+                             for _ in range(lm.n_superblocks(cfg)))
+    return params
+
+
+@pytest.mark.parametrize("runner", ["scanned-float", "unrolled-plan"])
+def test_granite_decode_updates_state_in_place(one_chip, monkeypatch,
+                                               runner):
+    """The decode cell's step (64 rows, the widest table of its 1,536
+    positions, 3,072 pages): the Mamba-2 state goes through
+    ``ssm_decode_step`` in place, and no op copies a whole layer's state
+    or KV pool."""
+    cfg = granite_stage()
+    params = abstract_served_params(cfg, runner, one_chip)
+    for mod in (pops, qops, sops):
+        monkeypatch.setattr(mod, "_on_tpu", lambda: True)
+    caches = jax.tree.map(
+        lambda a: spec(one_chip, a.shape, a.dtype),
+        lm.init_paged_caches(cfg, 64, PAGE, 3072, abstract=True))
+
+    def decode_greedy(p, tok, c, tbl, pos):
+        logits, c = lm.decode_step(cfg, p, {"tokens": tok}, c, pos,
+                                   tables=tbl)
+        return jnp.argmax(logits[:, -1, :cfg.vocab], -1), c
+
+    hlo = jax.jit(decode_greedy, donate_argnums=(2,)).lower(
+        params, spec(one_chip, (64, 1), jnp.int32), caches,
+        spec(one_chip, (64, 1536 // PAGE), jnp.int32),
+        spec(one_chip, (64,), jnp.int32)).compile().as_text()
+    assert hlo.count("custom-call(") >= 2
+    assert re.search(r"%ssm_decode_step[.\d]* = ", hlo)
+    pool = caches["l1"]["kv"]["k"]
+    layer_bytes = pool.size // pool.shape[0] * 2          # bf16, < state
+    assert pool_sized_ops(hlo, layer_bytes,
+                          _POOL_OPS | {"custom-call"}) == []
+
+
+def test_granite_prefill_compiles(one_chip, monkeypatch):
+    """The decode mix's longest prompt (1,024 tokens) through the planned
+    stage's paged prefill: the chunked SSD at chunk 256 and the paged
+    prefill kernel fit the chip."""
+    cfg = granite_stage()
+    params = abstract_served_params(cfg, "unrolled-plan", one_chip)
+    for mod in (pops, qops, sops):
+        monkeypatch.setattr(mod, "_on_tpu", lambda: True)
+    pools = {ln: {"kv": jax.tree.map(
+        lambda a: spec(one_chip, a.shape, a.dtype), c["kv"])}
+        for ln, c in lm.init_paged_caches(cfg, 64, PAGE, 3072,
+                                          abstract=True).items()
+        if "kv" in c}
+    text = jax.jit(steps.make_paged_prefill_step(cfg)).lower(
+        params, {"tokens": spec(one_chip, (1, 1024), jnp.int32)}, pools,
+        spec(one_chip, (1, 1024 // PAGE), jnp.int32),
+        spec(one_chip, (1,), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in text
