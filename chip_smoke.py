@@ -219,7 +219,7 @@ def decode_hlo_kernels(server, reqs) -> int:
     last_pos = max(len(r.prompt) for r in reqs) + NEW_TOKENS - 2
     width = server._live_width([types.SimpleNamespace(pos=last_pos)])
     compiled = server._decode_greedy.lower(
-        server.params, {"tokens": jnp.zeros((b, 1), jnp.int32)},
+        server.params, jnp.zeros((b + 1,), jnp.int32),
         backend.gather(), backend.device_tables(),
         jnp.zeros((b,), jnp.int32), width).compile()
     return compiled.as_text().count("tpu_custom_call")

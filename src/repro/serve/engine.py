@@ -137,13 +137,14 @@ def synthetic_plan(cfg, params, bits: int | None = None, seed: int = 0,
 class StepResult:
     """What one :meth:`InferenceServer.step` did.
 
-    ``produced`` maps uid -> tokens generated so far, for every request
+    ``produced`` maps uid -> tokens read back so far, for every request
     that gained a token this step (admission token or decode token);
-    ``idle`` means no decode ran (the engine jumped the clock to the
-    next arrival, or had nothing at all to do).  ``nan`` means NaN
-    logits were detected at the sampling host boundary: the step's
-    tokens were DISCARDED (no stream advanced, nothing finished) and
-    the caller should quarantine the server -- the fleet's failover
+    ``idle`` means no decode ran and no token was read back (the engine
+    jumped the clock to the next arrival, or had nothing at all to do).
+    ``nan`` means NaN logits were detected at the sampling host
+    boundary: the tokens they produced, and the step dispatched behind
+    them, were DISCARDED (nothing of them reached a stream or finished)
+    and the caller should quarantine the server -- the fleet's failover
     path recovers the in-flight requests onto healthy replicas."""
 
     admitted: list
@@ -164,6 +165,18 @@ class InferenceServer:
     token streams are identical on both backends.  Decoder-only
     token-frontend architectures only (enc-dec and vision/audio frontends
     need prompt-side encoders the request schema doesn't carry yet).
+
+    Sessions (``begin``/``submit``/``step``/``cancel``/``end``) deliver
+    decode tokens one ``step()`` call late on the device-sampled paths:
+    call k dispatches decode step k before it reads step k-1 back, so
+    the host's work for a step runs while the chip computes the next.
+    A request's first token arrives in the call that admits it, each
+    decode token in the call after the one that dispatched its step, and
+    a request of T tokens finishes within T calls of its admission.
+    ``end()`` discards a step still in flight unread; ``result()`` and
+    ``cancel()`` see only tokens read back.  Host sampling
+    (``sample_on_device=False``) needs the logits on the host, so it
+    reads each step back in the call that dispatched it.
     """
 
     def __init__(self, cfg, params, plan=None, *, max_len: int = 512,
@@ -247,49 +260,67 @@ class InferenceServer:
             donate_argnums=(2,), static_argnums=(5,))
 
         vocab = cfg.vocab
+        b = self.max_batch
 
-        def decode_sample(params, tokens, caches, tables, pos, temps,
+        # The device-sampled decode callables take and return the token
+        # vector, (max_batch + 1,) int32 on the device: entry s is slot
+        # s's next input token, the last entry the NaN-guard flag of the
+        # logits sampled into it.  A decode step's output vector is the
+        # next step's input as it stands, so step n+1 is dispatched
+        # before step n is read back; admissions write their first token
+        # into it (`sample`).  A row no request holds (position 0)
+        # decodes token 0.
+        def step_tokens(tok, pos):
+            return {"tokens": jnp.where(pos > 0, tok[:b], 0)[:, None]}
+
+        def token_vector(next_tok, row):
+            return jnp.concatenate(
+                [next_tok, jnp.isnan(row).any().astype(jnp.int32)[None]])
+
+        def decode_sample(params, tok, caches, tables, pos, temps,
                           topks, seeds, uids, tidx, need_top_k, width):
-            """One decode step + on-device batched sampling: only the
-            (B,) sampled ids (plus the scalar NaN-guard flag) cross back
-            to the host."""
+            """One decode step + on-device batched sampling."""
             logits, caches = lm.decode_step(
-                cfg, params, tokens, caches, pos,
+                cfg, params, step_tokens(tok, pos), caches, pos,
                 tables=_live_tables(tables, width))
             with jax.named_scope("sample"):
                 row = logits[:, -1, :vocab]
                 next_tok = sample_tokens_device(
                     row, temps, topks, seeds, uids, tidx,
                     need_top_k=need_top_k)
-                return next_tok, caches, jnp.isnan(row).any()
+                return token_vector(next_tok, row), caches
 
         self._decode_sample = jax.jit(decode_sample, donate_argnums=(2,),
                                       static_argnums=(10, 11))
 
-        def decode_greedy(params, tokens, caches, tables, pos, width):
+        def decode_greedy(params, tok, caches, tables, pos, width):
             """All-greedy fast path: plain argmax, no sort/Gumbel work."""
             logits, caches = lm.decode_step(
-                cfg, params, tokens, caches, pos,
+                cfg, params, step_tokens(tok, pos), caches, pos,
                 tables=_live_tables(tables, width))
             with jax.named_scope("sample"):
                 row = logits[:, -1, :vocab].astype(jnp.float32)
                 next_tok = jnp.argmax(row, axis=-1).astype(jnp.int32)
-                return next_tok, caches, jnp.isnan(row).any()
+                return token_vector(next_tok, row), caches
 
         self._decode_greedy = jax.jit(decode_greedy, donate_argnums=(2,),
                                       static_argnums=(5,))
-        # the NaN-guard flag rides back with the sampled id: a scalar
-        # crossing an already-paid host boundary, so corrupted (e.g.
-        # NaN-poisoned-plan) logits are caught before a garbage token
-        # can enter a client stream
-        def sample(lg, temps, topks, seeds, uids, tidx, need_top_k):
-            with jax.named_scope("sample"):
-                return (sample_tokens_device(lg[:, :vocab], temps, topks,
-                                             seeds, uids, tidx,
-                                             need_top_k=need_top_k),
-                        jnp.isnan(lg[:, :vocab]).any())
 
-        self._sample = jax.jit(sample, static_argnums=(6,))
+        # an admission's first token, sampled from its prefill logits
+        # into its slot of the token vector; the NaN flag is OR-ed in,
+        # so corrupted (e.g. NaN-poisoned-plan) logits are caught at the
+        # step's one readback before a garbage token can enter a stream
+        def sample(lg, tok, slot, temps, topks, seeds, uids, tidx,
+                   need_top_k):
+            with jax.named_scope("sample"):
+                row = lg[:, :vocab].astype(jnp.float32)
+                first = sample_tokens_device(row, temps, topks, seeds, uids,
+                                             tidx, need_top_k=need_top_k)
+                bad = jnp.isnan(row).any().astype(jnp.int32)
+                return tok.at[slot].set(first[0]).at[b].max(bad)
+
+        self._sample = jax.jit(sample, static_argnums=(8,))
+        self._tok0 = jnp.zeros((b + 1,), jnp.int32)
         # session state (see the "serving" section): None between runs
         self._sched = None
         self._now = 0
@@ -297,6 +328,7 @@ class InferenceServer:
         self._n_admitted = 0
         self._cancelled: dict = {}
         self._nan_detected = False
+        self._reset_flight()
         self.obs = None
         self._reg = None
         self.attach_obs(obs)
@@ -331,32 +363,30 @@ class InferenceServer:
         return out
 
     # ------------------------------------------------------- sampling glue
-    def _sample_first(self, logits_last, st_req, uid, tidx, rng):
-        """Sample from prefill logits (token index ``tidx`` of the
-        request's stream): device path or host fallback."""
+    def _sample_first(self, logits_last, st, tidx):
+        """Sample the admitted request's token ``tidx`` of its stream from
+        its prefill logits.  On the device path it is written into the
+        slot's entry of the token vector and read back with the step's
+        one readback; the host path reads the logits back and samples
+        here."""
+        sp = st.request.sampling
         if self.sample_on_device:
-            sp = st_req.sampling
-            tok, bad = self._sample(
-                logits_last.astype(jnp.float32),
-                jnp.asarray([sp.temperature], jnp.float32),
-                jnp.asarray([sp.top_k], jnp.int32),
-                jnp.asarray([sp.seed], jnp.int32),
-                jnp.asarray([uid], jnp.int32),
-                jnp.asarray([tidx], jnp.int32),
+            self._tok = self._sample(
+                logits_last, self._tok, np.int32(st.slot),
+                np.asarray([sp.temperature], np.float32),
+                np.asarray([sp.top_k], np.int32),
+                np.asarray([sp.seed], np.int32),
+                np.asarray([st.request.uid], np.int32),
+                np.asarray([tidx], np.int32),
                 0 < sp.top_k < self.cfg.vocab)
-            with span("serve.device_wait"):
-                bad = bool(np.asarray(bad))
-            if bad:
-                self._flag_nan()
-            with span("serve.device_wait"):
-                return int(np.asarray(tok)[0])
+            return
         with span("serve.device_wait"):
             row = np.asarray(logits_last.astype(jnp.float32))[0]
         vrow = row[: self.cfg.vocab]
         if np.isnan(vrow).any():
-            self._flag_nan()
-            return 0        # untrusted step; never reaches `finished`
-        return sample_token(vrow, st_req.sampling, rng)
+            self._flag_nan()   # untrusted step; never reaches `finished`
+            return
+        self._host_tok[st.slot] = sample_token(vrow, sp, st.rng)
 
     def _flag_nan(self):
         """Record a NaN detection at the sampling host boundary.  The
@@ -379,7 +409,17 @@ class InferenceServer:
     # returns the finished streams.  ``serve()`` is the batch
     # convenience wrapping the four; the fleet drives sessions directly
     # so it can interleave arrivals, deadline scans and cancellations
-    # with decode steps.
+    # with decode steps.  See the class docstring for when a token is
+    # delivered.
+
+    def _reset_flight(self):
+        """No decode step or first token in flight."""
+        self._tok = self._tok0  # the device token vector
+        self._host_tok = np.zeros((self.max_batch,), np.int32)
+        self._host_logits = None    # the host path's unread step
+        self._flight = []       # rows of the dispatched, unread decode step
+        self._fresh = []        # admissions whose first token is unread
+        self._collected = None  # the last readback's tokens, by slot
 
     def begin(self, requests=(), *, fresh_trace: bool = True):
         """Open a serving session (per-run trace reset, fresh scheduler,
@@ -398,6 +438,7 @@ class InferenceServer:
         self._n_admitted = 0
         self._cancelled: dict = {}   # uid -> (reason, tokens np.ndarray)
         self._nan_detected = False
+        self._reset_flight()
         for r in requests:
             self.submit(r)
         return self
@@ -419,11 +460,14 @@ class InferenceServer:
 
     @property
     def has_work(self) -> bool:
+        """Queued or resident requests remain (a retiring request stays
+        resident until its last token is read back)."""
         return self._sched is not None and self._sched.has_work
 
     def _admit(self) -> list:
         """Admit every arrived request the backend has memory for;
-        returns the admitted uids (in admission order)."""
+        returns the admitted uids (in admission order).  Each admission
+        samples its first token, read back with the step's readback."""
         sched, backend = self._sched, self.backend
         reg, tracer = self._reg, (self.obs.tracer
                                   if self.obs is not None else None)
@@ -468,44 +512,26 @@ class InferenceServer:
                     width=self._prefill_width)
             self._n_admitted += 1
             if entry.resume is None:
-                rng = make_rng(req.sampling, req.uid)
-                with span("serve.sample_first", uid=req.uid):
-                    tok = self._sample_first(logits, req, req.uid, 0, rng)
                 st = SlotState(request=req, slot=slot,
                                pos=int(tokens_np.size),
-                               remaining=req.sampling.max_tokens - 1,
-                               last_token=tok, out=[tok], rng=rng,
-                               order=self._n_admitted, handle=handle)
+                               remaining=req.sampling.max_tokens, out=[],
+                               rng=make_rng(req.sampling, req.uid))
             else:       # preempted request: continue its exact stream
                 st = entry.resume
-                with span("serve.sample_first", uid=req.uid):
-                    tok = self._sample_first(logits, req, req.uid,
-                                             len(st.out), st.rng)
                 st.slot = slot
                 st.pos = int(tokens_np.size)
-                st.out.append(tok)
-                st.last_token = tok
-                st.remaining -= 1
-                st.order = self._n_admitted
-                st.handle = handle
-            if tracer is not None:
-                # first residency yields the request's first token;
-                # a resume's admission token is a decode step of its
-                # ongoing stream
-                tracer.event(req.uid,
-                             "decode" if resumed else "first_token",
-                             n=len(st.out),
-                             pages_held=len(handle.pages), slot=slot)
+            st.remaining -= 1
+            st.pending = 1
+            st.order = self._n_admitted
+            st.handle = handle
+            with span("serve.sample_first", uid=req.uid):
+                self._sample_first(logits, st, len(st.out))
             sched.activate(slot, st)
+            self._fresh.append(st)
             admitted.append(req.uid)
-            # a NaN-flagged admission token is untrusted: leave the
-            # request resident so the quarantine/recovery path can
-            # strike it instead of letting garbage into `finished`
-            if (st.remaining <= 0 or st.pos >= self.max_len) \
-                    and not self._nan_detected:
+            if st.remaining <= 0 or st.pos >= self.max_len:
                 st.truncated = st.remaining > 0
-                backend.free(handle)
-                sched.complete(slot)
+                self._retire(st)
         return admitted
 
     def step(self) -> StepResult:
@@ -516,81 +542,131 @@ class InferenceServer:
             return self._step()
 
     def _step(self) -> StepResult:
-        sched, backend = self._sched, self.backend
+        sched = self._sched
         tracer = self.obs.tracer if self.obs is not None else None
         fin0 = len(sched.finished)
+        produced = {}
+
+        def result(**kw):
+            return StepResult(admitted=admitted, produced=produced,
+                              finished=list(sched.finished)[fin0:], **kw)
+
         with span("serve.admit"):
             admitted = self._admit()
-        # every admission yields one token (sampled from the prefill
-        # logits), so admitted uids are producers this step
-        produced = {}
-        for uid in admitted:
-            st = sched.finished.get(uid) or next(
-                (s for s in sched.active if s.request.uid == uid), None)
-            if st is not None:
-                produced[uid] = len(st.out)
         if self._nan_detected:
-            # admission sampling tripped the NaN guard: nothing
-            # completed (see _admit); surface and skip the decode
-            return StepResult(admitted=admitted, produced=produced,
-                              finished=list(sched.finished)[fin0:],
-                              nan=True)
-
-        active = sched.active
-        idle = False
-        if not active:
-            nxt = sched.next_arrival
-            if nxt is not None:
-                self._now = max(self._now + 1, nxt)   # jump to arrival
-            idle = True
-        else:
+            # a host-sampled admission tripped the NaN guard, or an
+            # earlier step did: nothing completes, the caller quarantines
+            return result(nan=True)
+        batch = [st for st in sched.active if not st.retiring]
+        unread = self._tok
+        if batch:
             # one batched decode step over the active slots
-            next_toks = self._decode_active(active)
+            self._launch(batch)
             self._n_steps += 1
-            if self._nan_detected:
-                # discard the whole step's tokens: no stream advances,
-                # nothing completes, the caller quarantines the server
-                return StepResult(admitted=admitted, produced=produced,
-                                  finished=list(sched.finished)[fin0:],
-                                  nan=True)
+        if self.sample_on_device and (self._flight or self._fresh):
+            # read back the previous step and this call's first tokens
+            # while the chip runs the step just dispatched
+            if not self._collect(unread, produced, tracer):
+                return result(nan=True)
+        ok = True
+        if batch:
+            self._flight = batch
             with span("serve.bookkeep"):
-                self._bookkeep(active, next_toks, produced, tracer)
+                ok = self._advance(batch, produced, tracer)
             self._now += 1
-        finished = list(sched.finished)[fin0:]
-        return StepResult(admitted=admitted, produced=produced,
-                          finished=finished, idle=idle)
+        elif sched.next_arrival is not None:
+            self._now = max(self._now + 1, sched.next_arrival)  # jump
+        if ok and not self.sample_on_device and (self._flight
+                                                 or self._fresh):
+            ok = self._collect(self._host_logits, produced, tracer)
+        return result(idle=not batch and not produced, nan=not ok)
 
-    def _bookkeep(self, active, next_toks, produced, tracer):
-        """Record each slot's decoded token, free the finished, and back
-        the survivors' next cache write."""
-        sched, backend = self._sched, self.backend
+    def _retire(self, st):
+        """``st``'s last token is sampled: free its pages now (the device
+        runs the step that writes them before any later prefill reuses
+        them); it completes when that token is read back."""
+        self.backend.free(st.handle)
+        st.retiring = True
+
+    def _advance(self, batch, produced, tracer) -> bool:
+        """Advance each row of the dispatched step past its sampled
+        token, retire the finished, and back the survivors' next cache
+        write.  False when a synchronising readback found NaN logits."""
+        sched = self._sched
         survivors = []
-        for st in active:
+        for st in batch:
             st.pos += 1
-            tok = next_toks[st.slot]
-            st.out.append(tok)
-            st.last_token = tok
             st.remaining -= 1
-            produced[st.request.uid] = len(st.out)
-            if tracer is not None:
-                tracer.event(st.request.uid, "decode", n=len(st.out),
-                             pages_held=len(st.handle.pages),
-                             slot=st.slot)
-            if st.remaining <= 0:
-                backend.free(st.handle)
-                sched.complete(st.slot)
-            elif st.pos >= self.max_len:
-                st.truncated = True
-                backend.free(st.handle)
-                sched.complete(st.slot)
+            st.pending += 1
+            if st.remaining <= 0 or st.pos >= self.max_len:
+                st.truncated = st.remaining > 0
+                self._retire(st)
             else:
                 survivors.append(st)
-        # page-backing AFTER every slot recorded its token: a
-        # preemption victim then always requeues with its full
-        # sampled stream (resume re-derives nothing)
+        # page-backing AFTER every finished slot freed its pages, as a
+        # step-by-step loop would
         for st in survivors:
             if sched.slots[st.slot] is st:   # not already preempted
-                self._append_or_preempt(sched, backend, st)
+                if not self._append_or_preempt(st, produced, tracer):
+                    return False
+        return True
+
+    def _collect(self, unread, produced, tracer) -> bool:
+        """The one blocking readback: the tokens of the step in flight
+        and of the admissions not yet read (the token vector ``unread``
+        on the device paths, the step's logits on the host path), and
+        the NaN flag with them.  Records every token of a request still
+        resident, completes the retiring, and clears what was in flight.
+        On NaN logits nothing is recorded and False is returned."""
+        sched = self._sched
+        rows, self._flight = self._flight, []
+        fresh, self._fresh = self._fresh, []
+        rows = [st for st in rows if sched.slots[st.slot] is st]
+        fresh = [st for st in fresh if sched.slots[st.slot] is st]
+        with span("serve.decode.collect"):
+            if self.sample_on_device:
+                with span("serve.device_wait"):
+                    vals = np.asarray(unread)
+                if vals[self.max_batch]:
+                    self._flag_nan()
+                    return False
+            else:
+                vals = self._host_tok   # the first tokens sampled so far
+                if rows:
+                    with span("serve.device_wait"):
+                        logits = np.asarray(unread.astype(jnp.float32))[
+                            :, -1, : self.cfg.vocab]
+                    if any(np.isnan(logits[st.slot]).any() for st in rows):
+                        # don't sample from poisoned rows (the host
+                        # sampler's softmax would propagate the NaN)
+                        self._flag_nan()
+                        return False
+            for st in fresh:
+                self._record(st, int(vals[st.slot]), produced, tracer)
+            if not self.sample_on_device:
+                for st in rows:
+                    vals[st.slot] = sample_token(
+                        logits[st.slot], st.request.sampling, st.rng)
+            self._collected = vals
+            toks = self._decode_active(rows)
+            for st in rows:
+                self._record(st, toks[st.slot], produced, tracer)
+        return True
+
+    def _record(self, st, tok, produced, tracer):
+        """Append one read-back token to ``st``'s stream."""
+        st.out.append(tok)
+        st.pending -= 1
+        produced[st.request.uid] = len(st.out)
+        if tracer is not None:
+            # a resume's admission token is a decode step of its
+            # ongoing stream
+            tracer.event(st.request.uid,
+                         "first_token" if len(st.out) == 1 else "decode",
+                         n=len(st.out), pages_held=len(st.handle.pages),
+                         slot=st.slot)
+        if st.retiring and not st.pending:
+            self._sched.complete(st.slot)
 
     def cancel(self, uid: int, reason: str = "cancelled"):
         """Cancel a queued or in-flight request, freeing its cache pages
@@ -600,7 +676,9 @@ class InferenceServer:
         fleet's failover path, and becomes the lifecycle terminal
         event.  Returns the tokens the request had generated so far
         (possibly empty), or None if the uid is not live in the
-        session."""
+        session.  A token of the request still in flight is dropped
+        when it is read back; pages it freed may be reused at once,
+        because the device runs that step before any later prefill."""
         if reason not in ("cancelled", "timeout", "crashed",
                           "quarantined"):
             raise ValueError(f"cancel reason must be 'cancelled', "
@@ -611,7 +689,8 @@ class InferenceServer:
         sched = self._sched
         for st in sched.active:
             if st.request.uid == uid:
-                self.backend.free(st.handle)   # before the event: the
+                if not st.retiring:            # retiring: already freed
+                    self.backend.free(st.handle)   # before the event: the
                 break                          # trace shows pages_held=0
         res = sched.cancel(uid, kind=reason)
         if res is None:
@@ -632,7 +711,8 @@ class InferenceServer:
 
     def end(self) -> dict:
         """Close the session: final stats + metrics publish; returns
-        ``{uid: np.ndarray(tokens)}`` for every finished request."""
+        ``{uid: np.ndarray(tokens)}`` for every finished request.  A
+        decode step still in flight is discarded unread."""
         sched = self._sched
         if sched is None:
             raise RuntimeError("no open session; call begin() first")
@@ -649,6 +729,7 @@ class InferenceServer:
         out = {uid: np.asarray(s.out, np.int32)
                for uid, s in sched.finished.items()}
         self._sched = None
+        self._reset_flight()
         return out
 
     def live_uids(self) -> list:
@@ -757,76 +838,67 @@ class InferenceServer:
         step = max(1, tw // 8)
         return min(tw, -(-need // step) * step)
 
-    def _decode_active(self, active) -> dict:
-        """One batched decode step; returns {slot: sampled token id}."""
+    def _launch(self, batch):
+        """Dispatch one batched decode step over ``batch`` (slot order).
+        The device paths replace the token vector by the step's output
+        without waiting on it; the host path keeps the step's logits for
+        its readback."""
         # which decode callable runs (the metrics label): when every
         # active row is greedy, the argmax decode, none of the sort/Gumbel
         # machinery (bit-identical to the full sampler)
         if not self.sample_on_device:
             path = "host"
-        elif all(st.request.sampling.greedy for st in active):
+        elif all(st.request.sampling.greedy for st in batch):
             path = "greedy"
         else:
             path = "sample"
+        overlapped = bool(self._flight)
         with span("serve.decode.inputs"):
-            tokens = np.zeros((self.max_batch, 1), np.int32)
             pos = np.zeros((self.max_batch,), np.int32)
-            for st in active:
-                tokens[st.slot, 0] = st.last_token
+            for st in batch:
                 pos[st.slot] = st.pos
             caches = self.backend.gather()
             tables = self.backend.device_tables()
-            width = self._live_width(active)
-            if path == "sample":
-                sampling = self._sampling_inputs(active)
-        try:
+            width = self._live_width(batch)
             if path == "host":
-                with span("serve.decode.launch"):
-                    logits, caches = self._decode(
-                        self.params, {"tokens": jnp.asarray(tokens)},
-                        caches, tables, jnp.asarray(pos), width)
-                    self.backend.commit(caches)
-                with span("serve.device_wait"):
-                    rows = np.asarray(logits.astype(jnp.float32))[
-                        :, -1, : self.cfg.vocab]
-                if any(np.isnan(rows[st.slot]).any() for st in active):
-                    self._flag_nan()
-                    # don't sample from poisoned rows (the host
-                    # sampler's softmax would propagate the NaN); step()
-                    # discards the step's tokens anyway
-                    return {st.slot: 0 for st in active}
-                return {st.slot: sample_token(rows[st.slot],
-                                              st.request.sampling, st.rng)
-                        for st in active}
-            with span("serve.decode.launch"):
-                if path == "greedy":
-                    next_tok, caches, bad = self._decode_greedy(
-                        self.params, {"tokens": jnp.asarray(tokens)},
-                        caches, tables, jnp.asarray(pos), width)
-                else:
-                    next_tok, caches, bad = self._decode_sample(
-                        self.params, {"tokens": jnp.asarray(tokens)},
-                        caches, tables, jnp.asarray(pos), *sampling,
-                        width)
-                self.backend.commit(caches)
-            with span("serve.device_wait"):
-                bad = bool(np.asarray(bad))
-            if bad:
-                self._flag_nan()
-            with span("serve.device_wait"):
-                ids = np.asarray(next_tok)
-            return {st.slot: int(ids[st.slot]) for st in active}
-        finally:
-            if self._reg is not None:
-                # one series per (path, width) == one compiled decode
-                # variant (width is a static argument of the jit)
-                self._reg.counter(
-                    "serve_decode_steps_total",
-                    "Batched decode steps by decode path and static "
-                    "live-table width",
-                    labels=("path", "width")).inc(
-                    path=path,
-                    width="dense" if width is None else str(width))
+                tokens = np.zeros((self.max_batch, 1), np.int32)
+                for st in batch:
+                    tokens[st.slot, 0] = self._host_tok[st.slot]
+            elif path == "sample":
+                sampling = self._sampling_inputs(batch)
+        with span("serve.decode.launch"):
+            if path == "host":
+                self._host_logits, caches = self._decode(
+                    self.params, {"tokens": jnp.asarray(tokens)},
+                    caches, tables, jnp.asarray(pos), width)
+            elif path == "greedy":
+                self._tok, caches = self._decode_greedy(
+                    self.params, self._tok, caches, tables, pos, width)
+            else:
+                self._tok, caches = self._decode_sample(
+                    self.params, self._tok, caches, tables, pos,
+                    *sampling, width)
+            self.backend.commit(caches)
+        if self._reg is not None:
+            # one series per (path, width) == one compiled decode
+            # variant (width is a static argument of the jit)
+            self._reg.counter(
+                "serve_decode_steps_total",
+                "Batched decode steps by decode path and static "
+                "live-table width",
+                labels=("path", "width")).inc(
+                path=path, width="dense" if width is None else str(width))
+            self._reg.counter(
+                "serve_decode_overlap_total",
+                "Decode steps dispatched while the previous step was "
+                "still unread (overlapped) or after reading it (synced)",
+                labels=("mode",)).inc(
+                mode="overlapped" if overlapped else "synced")
+
+    def _decode_active(self, active) -> dict:
+        """The read-back tokens of the decode step over ``active``:
+        {slot: sampled token id}."""
+        return {st.slot: int(self._collected[st.slot]) for st in active}
 
     def _sampling_inputs(self, active) -> tuple:
         """The on-device sampler's per-slot operands, and its trace-time
@@ -843,24 +915,32 @@ class InferenceServer:
             topks[st.slot] = sp.top_k
             seeds[st.slot] = sp.seed
             uids[st.slot] = st.request.uid
-            tidx[st.slot] = len(st.out)
+            tidx[st.slot] = len(st.out) + st.pending
         need_top_k = batch_need_top_k(
             [st.request.sampling for st in active], self.cfg.vocab,
             self._reg)
         return (jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(seeds),
                 jnp.asarray(uids), jnp.asarray(tidx), need_top_k)
 
-    def _append_or_preempt(self, sched, backend, st):
+    def _append_or_preempt(self, st, produced, tracer) -> bool:
         """Back the request's next cache write with storage; on pool
         exhaustion preempt the youngest-admitted active request (vLLM
         recompute-style) until the append succeeds or ``st`` itself was
-        evicted."""
+        evicted.  A victim leaves with its whole stream, so a token of
+        it still in flight is read back first; False when that readback
+        found NaN logits."""
+        sched, backend = self._sched, self.backend
         while True:
             try:
                 backend.append(st.handle)
-                return
+                return True
             except cache_mod.PoolExhausted:
-                victim = max(sched.active, key=lambda s: s.order)
+                victim = max((s for s in sched.active if not s.retiring),
+                             key=lambda s: s.order)
+                if victim.pending and not self._collect(
+                        self._tok if self.sample_on_device
+                        else self._host_logits, produced, tracer):
+                    return False
                 backend.free(victim.handle)
                 sched.preempt(victim.slot)
                 if self._reg is not None:
@@ -869,7 +949,7 @@ class InferenceServer:
                         "Requests preempted back to the queue on pool "
                         "exhaustion").inc()
                 if victim is st:
-                    return
+                    return True
 
     def generate(self, prompts: np.ndarray, sampling=None,
                  n_tokens: int | None = None) -> np.ndarray:

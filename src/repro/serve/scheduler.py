@@ -5,7 +5,8 @@ The :class:`~repro.serve.engine.InferenceServer` drives it: every decode
 step it first admits pending requests into free slots (the engine prefills
 each admitted request and writes its caches into the cache backend), then
 runs one batched decode step over the active slots and retires the ones
-that finished.  Requests may arrive over time (``Request.arrival`` in
+that finished.  A retiring request keeps its slot until the engine has
+read its last token back.  Requests may arrive over time (``Request.arrival`` in
 decode steps) -- the streaming-arrivals serving mode -- and more requests
 than slots simply queue.
 
@@ -53,13 +54,15 @@ class SlotState:
     slot: int
     pos: int                           # next cache write position
     remaining: int                     # tokens still to sample
-    last_token: int
-    out: list
+    out: list                          # tokens read back so far
     rng: np.random.Generator           # host-fallback sampling stream
     truncated: bool = False
     order: int = 0                     # admission sequence (preemption
     #                                    picks the youngest victim)
     handle: object = None              # CacheHandle of the cache backend
+    pending: int = 0                   # tokens sampled, not yet read back
+    retiring: bool = False             # last token sampled, pages freed;
+    #                                    completes once it is read back
 
 
 @dataclasses.dataclass

@@ -139,7 +139,7 @@ class TestMemoryAwareScheduler:
         return SlotState(request=req, slot=slot,
                          pos=entry.tokens().size,
                          remaining=req.sampling.max_tokens,
-                         last_token=0, out=list(entry.tokens()[
+                         out=list(entry.tokens()[
                              req.prompt.size:]),
                          rng=make_rng(req.sampling, req.uid))
 

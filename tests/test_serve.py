@@ -3,18 +3,25 @@ batching scheduler, PackedLinear vs. the per-layer mixed_precision_matmul
 oracle, float/quantized InferenceServer parity (batched == one-by-one ==
 streaming), plan round-trips into quantized decode, fully-pruned layers,
 and the PeriodicEval assignment cache."""
+import dataclasses
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import proptest as pt
+from repro import obs
+from repro.chaos import inject as chaos_inject
 from repro.configs import registry
 from repro.launch import steps
 from repro.models import lm
 from repro.nn import quantized as nnq
+from repro.serve import cache as cache_mod
 from repro.serve import engine
-from repro.serve.sampling import SamplingParams, make_rng, sample_token
+from repro.serve.sampling import (SamplingParams, make_rng, sample_token,
+                                  sample_tokens_device)
 from repro.serve.scheduler import Request, Scheduler
 
 
@@ -293,8 +300,8 @@ def _dummy_state(entry, slot):
     from repro.serve.scheduler import SlotState
     req = entry.request
     return SlotState(request=req, slot=slot, pos=req.prompt.size,
-                     remaining=req.sampling.max_tokens, last_token=0,
-                     out=[], rng=make_rng(req.sampling, req.uid))
+                     remaining=req.sampling.max_tokens, out=[],
+                     rng=make_rng(req.sampling, req.uid))
 
 
 # ---------------------------------------------------------------------------
@@ -693,3 +700,325 @@ class TestPeriodicEvalCache:
             k: v + 1.0 for k, v in ts["sp"]["mps"]["gamma"].items()}
         pe.on_step(js, state, 2, {}, ts)
         assert calls["n"] == 2
+
+
+# ---------------------------------------------------------------------------
+# the overlapped serve loop: step n+1 dispatched before step n is read back
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def granite():
+    cfg = registry.get("granite-4.0-h-small-smoke")
+    return cfg, lm.init_params(cfg, jax.random.key(3))
+
+
+def _sync_reference(server, req):
+    """``req``'s stream from a plain synchronous loop over the server's
+    bound parameters: one request, dense caches, fused prefill, then one
+    decode step at a time, each token read back before the next step is
+    run.  Tokens are drawn as the server draws them: argmax when greedy,
+    else the on-device sampler with the request's (seed, uid, index)."""
+    cfg, params, sp = server.cfg, server.params, req.sampling
+    prompt = np.asarray(req.prompt, np.int32)
+    if not hasattr(server, "_ref_steps"):
+        server._ref_steps = (
+            jax.jit(steps.make_prefill_step(cfg)),
+            jax.jit(lambda p, t, c, pos: lm.decode_step(cfg, p, t, c, pos)))
+    prefill, decode = server._ref_steps
+    caches = lm.init_caches(cfg, 1, server.max_len)
+    logits, pc = prefill(params, {"tokens": jnp.asarray(prompt[None])})
+    caches = jax.tree.map(
+        lambda big, small: jax.lax.dynamic_update_slice(
+            big, small.astype(big.dtype), (0,) * big.ndim), caches, pc)
+
+    def draw(row, tidx):
+        row = row[:, :cfg.vocab]
+        if sp.greedy:
+            return int(np.argmax(np.asarray(row.astype(jnp.float32))[0]))
+        tok = sample_tokens_device(
+            row, jnp.asarray([sp.temperature], jnp.float32),
+            jnp.asarray([sp.top_k], jnp.int32),
+            jnp.asarray([sp.seed], jnp.int32),
+            jnp.asarray([req.uid], jnp.int32),
+            jnp.asarray([tidx], jnp.int32),
+            need_top_k=0 < sp.top_k < cfg.vocab)
+        return int(np.asarray(tok)[0])
+
+    out = [draw(logits[:, -1].astype(jnp.float32), 0)]
+    pos = prompt.size
+    while len(out) < sp.max_tokens and pos < server.max_len:
+        logits, caches = decode(
+            params, {"tokens": jnp.asarray([[out[-1]]], jnp.int32)},
+            caches, jnp.asarray([pos], jnp.int32))
+        out.append(draw(logits[:, -1], len(out)))
+        pos += 1
+    return np.asarray(out, np.int32)
+
+
+def _overlap_reqs(cfg, sampled, seed=0):
+    """Four requests over two slots, arriving while others decode: two
+    at step 0, two later; budgets differ so slots free at different
+    steps, and one request finishes at admission."""
+    rng = np.random.default_rng(seed)
+    sps = [SamplingParams(max_tokens=6), SamplingParams(max_tokens=4),
+           SamplingParams(max_tokens=1), SamplingParams(max_tokens=5)]
+    if sampled:
+        sps[0] = SamplingParams(temperature=0.8, top_k=12, max_tokens=6,
+                                seed=11)
+        sps[3] = SamplingParams(temperature=0.7, max_tokens=5, seed=4)
+    lens, arrivals = (5, 9, 5, 9), (0, 0, 2, 3)
+    return [Request(uid=i, sampling=sps[i], arrival=arrivals[i],
+                    prompt=rng.integers(0, cfg.vocab, size=lens[i])
+                    .astype(np.int32))
+            for i in range(4)]
+
+
+_SERVERS = {}
+
+
+def _shared_server(arch, cfg, params, cache, plan):
+    """One server per (arch, cache, plan) for the parity matrix, so its
+    cases share compiled programs."""
+    key = (arch, cache, plan)
+    if key not in _SERVERS:
+        _SERVERS[key] = _server(cfg, params, cache, plan)
+    return _SERVERS[key]
+
+
+def _server(cfg, params, cache, plan, **kw):
+    plan = engine.synthetic_plan(cfg, params, seed=0) if plan else None
+    paged = {"cache": "paged", "page_size": 4, "pages": 24} \
+        if cache == "paged" else {}
+    kw.setdefault("max_len", 32)
+    kw.setdefault("max_batch", 2)
+    return engine.InferenceServer(cfg, params, plan, **paged, **kw)
+
+
+def _run(server, reqs, before_step=None):
+    """Drive a session step by step; returns the finished streams and
+    the StepResults."""
+    server.begin(reqs)
+    results = []
+    while server.has_work:
+        if before_step is not None:
+            before_step(server, len(results))
+        results.append(server.step())
+        assert not results[-1].nan
+    return server.end(), results
+
+
+class TestOverlappedDecode:
+    @pytest.mark.parametrize("cache", ["paged", "dense"])
+    @pytest.mark.parametrize("plan", [False, True], ids=["float", "plan"])
+    @pytest.mark.parametrize("sampled", [False, True],
+                             ids=["greedy", "sampled"])
+    def test_streams_match_the_synchronous_reference(self, llama, cache,
+                                                     plan, sampled):
+        cfg, params = llama
+        server = _shared_server("llama", cfg, params, cache, plan)
+        reqs = _overlap_reqs(cfg, sampled)
+        out, _ = _run(server, reqs)
+        for r in reqs:
+            np.testing.assert_array_equal(out[r.uid],
+                                          _sync_reference(server, r))
+
+    @pytest.mark.parametrize("cache,sampled", [("paged", True),
+                                               ("dense", False)])
+    def test_ssm_state_in_flight_matches_the_reference(self, granite, cache,
+                                                       sampled):
+        cfg, params = granite
+        server = _shared_server("granite", cfg, params, cache, False)
+        reqs = _overlap_reqs(cfg, sampled, seed=1)
+        out, _ = _run(server, reqs)
+        for r in reqs:
+            np.testing.assert_array_equal(out[r.uid],
+                                          _sync_reference(server, r))
+
+    def test_admissions_while_a_step_is_in_flight(self, llama):
+        cfg, params = llama
+        server = _server(cfg, params, "paged", False)
+        reqs = _overlap_reqs(cfg, sampled=True)
+        in_flight = []
+        out, results = _run(server, reqs, lambda srv, _: in_flight.append(
+            bool(srv._flight)))
+        # the late arrivals were admitted while a decode step was unread
+        late = {r.uid for r in reqs if r.arrival > 0}
+        assert any(late & set(res.admitted) and flying
+                   for res, flying in zip(results, in_flight))
+        # each admission's first token arrives in the call admitting it
+        for res in results:
+            for uid in res.admitted:
+                assert uid in res.produced
+        for r in reqs:
+            np.testing.assert_array_equal(out[r.uid],
+                                          _sync_reference(server, r))
+        assert server.stats["memory"]["pages_in_use"] == 0
+
+    def test_request_finishes_within_its_token_count_of_calls(self, llama):
+        cfg, params = llama
+        server = _server(cfg, params, "paged", False, max_batch=4)
+        reqs = _overlap_reqs(cfg, sampled=False)
+        _, results = _run(server, reqs)
+        admitted_at = {uid: k for k, res in enumerate(results)
+                       for uid in res.admitted}
+        finished_at = {uid: k for k, res in enumerate(results)
+                       for uid in res.finished}
+        for r in reqs:
+            assert finished_at[r.uid] - admitted_at[r.uid] + 1 \
+                <= r.sampling.max_tokens
+
+    def test_cancel_in_flight_slot_then_reuse_its_pages(self, llama):
+        cfg, params = llama
+        # six pages: the two requests hold five after two steps, so the
+        # newcomer's three (two and the reservation) need the freed ones
+        server = _server(cfg, params, "paged", False, max_batch=2)
+        server.backend = cache_mod.make_backend(
+            "paged", cfg, 2, 32, page_size=4, n_pages=6)
+        reqs = _overlap_reqs(cfg, sampled=True)[:2]
+        server.begin(reqs)
+        server.step()                     # both admitted, step 1 in flight
+        server.step()                     # step 2 in flight, 1 read back
+        victim = next(st for st in server._sched.active
+                      if st.request.uid == 0)
+        assert victim.pending == 1        # its next token is unread
+        pages = set(victim.handle.pages)
+        held = server.backend.memory_report()["pages_in_use"]
+        toks = server.cancel(0)
+        assert toks.size == 2             # read-back tokens only
+        assert server.backend.memory_report()["pages_in_use"] \
+            == held - len(pages)
+        newcomer = Request(uid=7, prompt=reqs[0].prompt[::-1].copy(),
+                           sampling=SamplingParams(temperature=0.9,
+                                                   top_k=5, max_tokens=5,
+                                                   seed=2))
+        server.submit(newcomer)
+        res = server.step()               # the in-flight step runs first
+        assert res.admitted == [7]
+        st = next(s for s in server._sched.active if s.request.uid == 7)
+        assert pages & set(st.handle.pages)   # the freed pages reused
+        while server.has_work:
+            server.step()
+        out = server.end()
+        assert set(out) == {1, 7}
+        assert server.backend.memory_report()["pages_in_use"] == 0
+        np.testing.assert_array_equal(out[7],
+                                      _sync_reference(server, newcomer))
+        np.testing.assert_array_equal(out[1],
+                                      _sync_reference(server, reqs[1]))
+
+    def test_preemption_with_a_step_in_flight(self, llama):
+        cfg, params = llama
+        sp = SamplingParams(temperature=0.6, top_k=10, max_tokens=8,
+                            seed=3)
+        rng = np.random.default_rng(0)
+        reqs = [Request(uid=i, sampling=sp,
+                        prompt=rng.integers(0, cfg.vocab, size=n)
+                        .astype(np.int32))
+                for i, n in enumerate((4, 9, 6, 13))]
+        server = engine.InferenceServer(
+            cfg, params, max_len=32, max_batch=3, cache="paged",
+            page_size=4, pages=7, obs=obs.Observability())
+        out, _ = _run(server, reqs)
+        assert server.stats["preemptions"] > 0
+        for r in reqs:
+            np.testing.assert_array_equal(out[r.uid],
+                                          _sync_reference(server, r))
+        mode = server.obs.registry.counter("serve_decode_overlap_total",
+                                           labels=("mode",))
+        # a preemption reads the victim's in-flight token first, so the
+        # step after it is dispatched with nothing unread
+        assert mode.value(mode="synced") > 1
+        assert server.stats["memory"]["pages_in_use"] == 0
+
+    def test_nan_poisoned_plan_reaches_no_stream(self, llama):
+        cfg, params = llama
+        server = _server(cfg, params, "paged", True, max_batch=2)
+        reqs = _overlap_reqs(cfg, sampled=False)[:2]
+        reqs[1] = dataclasses.replace(
+            reqs[1], sampling=SamplingParams(max_tokens=1))
+        ref = {r.uid: _sync_reference(server, r) for r in reqs}
+        # poisoned from the start: the first readback trips the guard,
+        # and the request that would finish at admission does not
+        undo = chaos_inject.poison_params(server)
+        server.begin(reqs)
+        res = server.step()
+        assert res.nan and not res.finished and not res.produced
+        assert server.step().nan          # the session stays poisoned
+        assert [server.cancel(r.uid).size for r in reqs] == [0, 0]
+        server.end()
+        undo()
+        # poisoned mid-session (a fresh server: the poisoned steps left
+        # NaN in pages of the pool): the step read back after the
+        # poisoning is clean, the next holds NaN, and the step behind it
+        # is discarded
+        reqs = _overlap_reqs(cfg, sampled=False)[:2]
+        server = _server(cfg, params, "paged", True, max_batch=2)
+        ref = {r.uid: _sync_reference(server, r) for r in reqs}
+        server.begin(reqs)
+        server.step()
+        server.step()
+        read = {st.request.uid: len(st.out) for st in server._sched.active}
+        undo = chaos_inject.poison_params(server)
+        try:
+            assert not server.step().nan  # reads the last clean step
+            res = server.step()
+            assert res.nan and not res.finished and not res.produced
+            for r in reqs:
+                toks = server.cancel(r.uid, reason="quarantined")
+                assert toks.size == read[r.uid] + 1
+                np.testing.assert_array_equal(toks, ref[r.uid][:toks.size])
+            server.end()
+        finally:
+            undo()
+
+    @pytest.mark.parametrize("host", [False, True], ids=["greedy", "host"])
+    def test_overlap_counter(self, llama, host):
+        cfg, params = llama
+        server = _server(cfg, params, "paged", False,
+                         sample_on_device=not host, obs=obs.Observability())
+        # three requests over two slots, budgets apart so a slot is
+        # always decoding until the last token: every step but the first
+        # is dispatched with its predecessor unread
+        rng = np.random.default_rng(5)
+        reqs = [Request(uid=i, sampling=SamplingParams(max_tokens=n),
+                        prompt=rng.integers(0, cfg.vocab, size=5 + i)
+                        .astype(np.int32))
+                for i, n in enumerate((6, 9, 4))]
+        _run(server, reqs)
+        mode = server.obs.registry.counter("serve_decode_overlap_total",
+                                           labels=("mode",))
+        steps_ = server.stats["decode_steps"]
+        if host:
+            assert mode.value(mode="synced") == steps_
+            assert mode.value(mode="overlapped") == 0
+        else:
+            assert mode.value(mode="synced") == 1
+            assert mode.value(mode="overlapped") == steps_ - 1
+
+    def test_compiled_decode_variants_over_a_walk(self, llama):
+        """A solo walk across the live-width buckets compiles one greedy
+        decode per width, as the step-by-step loop did, and one
+        first-token sampler."""
+        cfg, params = llama
+        server = _server(cfg, params, "paged", False, max_len=64,
+                         max_batch=2, obs=obs.Observability())
+        prompt = np.arange(3, 12, dtype=np.int32)
+        n = 40
+        walk = Request(uid=0, prompt=prompt,
+                       sampling=SamplingParams(max_tokens=n))
+        out, _ = _run(server, [walk])
+        assert out[0].size == n
+        widths = {server._live_width([types.SimpleNamespace(pos=p)])
+                  for p in range(prompt.size, prompt.size + n - 1)}
+        assert len(widths) > 1
+        assert server._decode_greedy._cache_size() == len(widths)
+        assert server._decode_sample._cache_size() == 0
+        assert server._sample._cache_size() == 1
+        series = server.obs.registry.snapshot()[
+            "serve_decode_steps_total"]["series"]
+        assert {s["labels"]["width"] for s in series} == \
+            {str(w) for w in widths}
+        # a second session compiles nothing more
+        _run(server, [walk])
+        assert server._decode_greedy._cache_size() == len(widths)
+        assert server._sample._cache_size() == 1

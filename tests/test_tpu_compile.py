@@ -217,8 +217,7 @@ def test_paged_programs_hold_no_pool_copy(one_chip, two_layer_lm,
     monkeypatch.setattr(qops, "_on_tpu", lambda: True)
     args = {
         "decode_greedy": lambda: server._decode_greedy.lower(
-            *_abstract((server.params, {"tokens": np.zeros((4, 1),
-                                                           np.int32)},
+            *_abstract((server.params, np.zeros((5,), np.int32),
                         be.gather(), be.device_tables(),
                         np.zeros((4,), np.int32)), one_chip),
             be.table_width),
